@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import csv_row, timeit
 from repro.core import lut, ternary
-from repro.core.dataflow import HBM_BW, PEAK_FLOPS_INT8
+from repro.core import hw
 
 C = 4
 # The paper's Fig. 10 kernel shapes (N, K, M).
@@ -61,14 +61,15 @@ def measured(quick: bool = False):
 def chip_scaling():
     """Roofline chip-scaling of one 2B-4T BitLinear layer set (analytic)."""
     rows = []
+    pk = hw.chip_peaks()
     for chips in CHIPS:
         # Per-chip share of the 2B-4T decode GEMV workload (M sharded).
         n, k, m = 1, 2560, 6912 * 3  # qkv+mlp aggregate width
         m_local = max(m // chips, 128)
         flops = 2 * n * k * m_local
         mem = k * m_local * 0.25 + n * k + n * m_local * 4
-        t_c = flops / PEAK_FLOPS_INT8
-        t_m = mem / HBM_BW
+        t_c = flops / pk.int8_ops
+        t_m = mem / pk.hbm_bw
         t = max(t_c, t_m)
         rows.append({"chips": chips, "t_us": t * 1e6,
                      "bound": "memory" if t_m > t_c else "compute"})

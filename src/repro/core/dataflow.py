@@ -26,14 +26,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-# TPU v5e single-chip constants — shared with launch/roofline.py via core/hw.
-from repro.core.hw import (  # noqa: F401  (re-exported for back-compat)
-    HBM_BW,
-    PEAK_FLOPS_BF16,
-    PEAK_FLOPS_INT8,
-    SPARSE_ISSUE_TAX,
-    VMEM_BYTES,
-)
+from repro.core import hw
 from repro.plan import registry as _registry
 from repro.plan.registry import (  # noqa: F401  (canonical home is the registry)
     DEFAULT_DENSITY,
@@ -164,7 +157,7 @@ def sparse_break_even(n: int, k: int, m: int, c: int = 4,
 
 
 def select_dataflow(n: int, k: int, m: int, c: int = 4,
-                    vmem_budget: int = VMEM_BYTES) -> str:
+                    vmem_budget: int | None = None) -> str:
     """AP vs OP (paper Fig. 7).
 
     AP pins the activation/LUT tile in VMEM and streams weights: write-back of
@@ -175,7 +168,12 @@ def select_dataflow(n: int, k: int, m: int, c: int = 4,
     Heuristic mirror of the paper's empirical rule: high activation reuse
     (large n*k working set relative to outputs) -> AP; output-channel-heavy
     GEMV (m >> n) -> OP.
+
+    ``vmem_budget`` defaults to the VMEM one Pallas kernel may use on the
+    planned chip: Mosaic's scoped limit, not the chip's whole VMEM.
     """
+    if vmem_budget is None:
+        vmem_budget = hw.chip_peaks().vmem_scoped_bytes
     act_bytes = n * k                      # int8 activations
     lut_bytes = n * (k / c) * (2 ** c) * 2  # bf16 shared LUTs
     out_bytes = n * m * 4                  # f32 accumulators

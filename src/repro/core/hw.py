@@ -1,8 +1,12 @@
-"""TPU v5e single-chip hardware constants — the one shared definition.
+"""Per-chip hardware constants — the one shared definition.
 
-Previously duplicated between ``core/dataflow.py`` (kernel selection cost
-model) and ``launch/roofline.py`` (dry-run roofline extraction); both now
-import from here so a calibration tweak cannot desynchronize the two models.
+``PEAKS`` holds each chip's published peaks, keyed by the ``device_kind``
+that JAX reports.  Every cost model (the kernel registry's rooflines, the
+dataflow choice, the dry-run roofline) reads them from here, so the planner
+and the roofline cannot drift apart.  :func:`chip_peaks` resolves the entry
+for the device the planner runs on.  A TPU whose kind is not in the table is
+an error, not a silent v5e.  Off the TPU (CPU tests, dry-runs) planning
+targets the entry named by ``OFF_TPU_KIND``.
 
 Besides the fixed datasheet numbers, this module owns the **calibratable**
 cost-model constants.  ``SPARSE_ISSUE_TAX`` started life as an analytic guess
@@ -15,13 +19,51 @@ measured machine overrides the guess without touching the cost formulas.
 """
 from __future__ import annotations
 
+import functools
 import json
+from dataclasses import dataclass
 
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-PEAK_FLOPS_INT8 = 394e12       # int8 ops/s (2x bf16 on the v5e MXU)
-HBM_BW = 819e9                 # bytes/s
-VMEM_BYTES = 128 * 1024 * 1024
-ICI_LINK_BW = 50e9             # bytes/s per ICI link (~ spec value)
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float          # FLOP/s
+    int8_ops: float            # int8 op/s
+    hbm_bw: float              # bytes/s
+    hbm_bytes: float
+    ici_link_bw: float         # bytes/s per inter-chip link
+    vmem_scoped_bytes: int     # Mosaic's default scoped VMEM limit per kernel
+
+
+# Google Cloud TPU documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s
+# int8, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect
+# (four links of 50 GB/s).  The scoped VMEM limit is Mosaic's default on v5e.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bw=819e9, hbm_bytes=16e9,
+        ici_link_bw=50e9, vmem_scoped_bytes=16 * 1024 * 1024),
+}
+
+# The chip that planning targets when the process holds no TPU.
+OFF_TPU_KIND = "TPU v5 lite"
+
+
+@functools.cache
+def chip_peaks() -> ChipPeaks:
+    """Peaks of the chip this process plans for: its own TPU, or the
+    ``OFF_TPU_KIND`` entry off the TPU.  Raises on an unlisted TPU kind."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return PEAKS[OFF_TPU_KIND]
+    try:
+        return PEAKS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks for TPU kind {dev.device_kind!r} in repro.core.hw."
+            f"PEAKS (known: {sorted(PEAKS)}); add its published figures "
+            "before planning on it") from None
+
 
 # Issue-efficiency tax on the sparse kernels' live-block work (analytic
 # default; see module docstring).  Puts the break-even near 1/1.1 ~ 0.9 live

@@ -1,8 +1,10 @@
 """Public jitted wrappers for the T-SAR Pallas kernels.
 
-Handles activation quantization, shape padding to tile multiples, leading-dim
-flattening, and interpret-mode fallback on non-TPU backends (this container is
-CPU-only; TPU is the compilation target, interpret mode the validation path).
+Handles activation quantization, shape padding to tile multiples and
+leading-dim flattening.  ``interpret=None`` compiles the kernels with Mosaic
+on a TPU and runs them in the Pallas interpreter on any other backend: that
+is how the CPU tests validate them.  A run that must prove the compiled
+kernel passes ``interpret=False`` (``chip_smoke.py`` does).
 """
 from __future__ import annotations
 

@@ -4,15 +4,18 @@ This kernel is the literal transcription of the paper's two-instruction
 pipeline onto Pallas/TPU:
 
 * **TLUT_cxs** (Fig. 6(b)) — for every activation block of size ``c``, build
-  the shared binary LUT ``S[p] = sum_i bit_i(p) * a_i`` (2^c entries).  Here
-  that is a tiny (c -> 2^c) matmul executed in VMEM scratch; the LUT never
-  exists outside the kernel, exactly like the YMM-resident tables.
+  the shared binary LUT ``S[p] = sum_i bit_i(p) * a_i`` (2^c entries), each
+  entry one vector add from a smaller one.  The activations arrive in the
+  channel-major order of ``tsar_matmul.deinterleave``, so ``a_i`` for a
+  whole tile of blocks is one lane-aligned slice and the LUT is 2^c - 1
+  VMEM vectors; it never exists outside the kernel, exactly like the
+  YMM-resident tables.
 * **TGEMV_kxm** (Fig. 6(c)) — consume the LUTs against pre-encoded weight
   indices with fused accumulation.  A gather from a 2^c-entry table is, on
-  TPU, a one-hot (2^c-wide) matmul — the MXU plays the role of the SIMD
-  adder trees.  We fuse the paper's two gathers (dense/sparse planes) into a
-  single combined one-hot operand: ``comb = 2*onehot(idx_pos) +
-  onehot(idx_zero)`` so that ``y_block = S_b @ comb_b - sum(a_block)``
+  TPU, a one-hot matmul — the MXU plays the role of the SIMD adder trees.
+  We fuse the paper's two gathers (dense/sparse planes) into a single
+  combined one-hot operand per LUT entry: ``comb_p = 2*(idx_pos == p) +
+  (idx_zero == p)`` so that ``y = sum_p S_p @ comb_p - sum(a)``
   (DESIGN.md Sec. 2.1 single-LUT identity).
 
 Grid: (m_tiles, b_tiles) with the block axis innermost; the (N, bm) f32
@@ -28,6 +31,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tsar_matmul import deinterleave
+
+
+def vmem_bytes(n: int, bb: int, bm: int, c: int) -> int:
+    """VMEM one grid step of :func:`tsar_lut_gemv` needs: its in/out blocks,
+    double-buffered (rows pad to 8 sublanes), the f32 accumulator, the
+    2^c - 1 LUT entries, the widened indices and one one-hot operand.
+    Compare with the chip's scoped VMEM limit (``repro.core.hw``)."""
+    rows = -(-n // 8) * 8
+    blocks = rows * bb * c * 4 + 2 * bb * bm + 8 * bm * 4 + rows * bm * 4
+    temps = ((1 << c) - 1) * rows * bb * 4 + 2 * bb * bm * 4 + 2 * bb * bm * 4
+    return 2 * blocks + rows * bm * 4 + temps
+
 
 def _kernel(a_ref, ipos_ref, izero_ref, wsc_ref, o_ref, acc_ref, *,
             c: int, b_steps: int):
@@ -37,33 +53,37 @@ def _kernel(a_ref, ipos_ref, izero_ref, wsc_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    n = a_ref.shape[0]
     bb = ipos_ref.shape[0]          # blocks in this tile
-    lut_w = 1 << c
+    a = a_ref[...]                  # (n, c*bb), channel-major (see deinterleave)
+    # a_i[:, b] is channel i of block b: one lane-aligned slice per bit.
+    a_bits = [a[:, i * bb:(i + 1) * bb] for i in range(c)]
 
-    # ---- TLUT: build shared binary LUTs in VMEM -------------------------
-    a_blocks = a_ref[...].reshape(n, bb, c)
-    # bits[p, i] = bit_i(p), built in-kernel via iota (no captured constants).
-    p_iota = jax.lax.broadcasted_iota(jnp.int32, (lut_w, c), 0)
-    i_iota = jax.lax.broadcasted_iota(jnp.int32, (lut_w, c), 1)
-    bits = ((p_iota >> i_iota) & 1).astype(jnp.float32)           # (2^c, c)
-    s = jax.lax.dot_general(                                       # (n, bb, 2^c)
-        a_blocks, bits,
-        dimension_numbers=(((2,), (1,)), ((), ())),
-    )
-    tot = jnp.sum(a_blocks, axis=(1, 2))                           # (n,)
+    # ---- TLUT: build the shared binary LUT in VMEM ----------------------
+    # S_p = sum_i bit_i(p) * a_i, each entry one add from a smaller one
+    # (S_0 = 0 is never materialized: it gathers nothing).
+    lut = [None] * (1 << c)
+    for p in range(1, 1 << c):
+        low = p & -p
+        a_low = a_bits[low.bit_length() - 1]
+        lut[p] = a_low if p == low else lut[p ^ low] + a_low    # (n, bb)
+    tot = jnp.sum(lut[(1 << c) - 1], axis=1, keepdims=True)     # (n, 1)
 
     # ---- TGEMV: combined one-hot gather + fused accumulation ------------
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bb, lut_w, 1), 1)
-    ip = ipos_ref[...].astype(jnp.int32)[:, None, :]               # (bb, 1, bm)
-    iz = izero_ref[...].astype(jnp.int32)[:, None, :]
-    comb = (2.0 * (iota == ip) + 1.0 * (iota == iz)).astype(jnp.float32)
-    # y[n, m] += sum_b S[n, b, :] @ comb[b, :, m]
-    contrib = jax.lax.dot_general(
-        s, comb,
-        dimension_numbers=(((2,), (1,)), ((1,), (0,))),            # batch over b
-    )                                                              # (bb, n, bm)
-    acc_ref[...] += jnp.sum(contrib, axis=0) - tot[:, None]
+    # y[n, m] += sum_p S_p[n, :] @ comb_p[:, m] - tot[n], with
+    # comb_p = 2*onehot(idx_pos == p) + onehot(idx_zero == p).
+    ip = ipos_ref[...].astype(jnp.int32)                          # (bb, bm)
+    iz = izero_ref[...].astype(jnp.int32)
+    acc = acc_ref[...] - tot
+    for p in range(1, 1 << c):
+        comb = (2.0 * (ip == p).astype(jnp.float32)
+                + (iz == p).astype(jnp.float32))
+        acc += jax.lax.dot_general(
+            lut[p], comb,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+    acc_ref[...] = acc
 
     @pl.when(bstep == b_steps - 1)
     def _finish():
@@ -106,5 +126,6 @@ def tsar_lut_gemv(
         out_shape=jax.ShapeDtypeStruct((n, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, bm), jnp.float32)],
         interpret=interpret,
-    )(a.astype(jnp.float32), idx_pos, idx_zero, w_scale.reshape(1, m))
+    )(deinterleave(a.astype(jnp.float32), bb * c, c), idx_pos, idx_zero,
+      w_scale.reshape(1, m))
     return out

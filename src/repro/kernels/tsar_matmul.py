@@ -1,10 +1,17 @@
-"""Production T-SAR kernel: packed-ternary matmul, decode-in-VMEM -> MXU.
+"""T-SAR MXU kernel: packed-ternary matmul, decode-in-VMEM -> MXU.
 
-This is the TPU-native realization of the paper's in-register dataflow
-(DESIGN.md Sec. 2): the 2-bit weight bitplanes are the ONLY weight bytes that
-cross HBM; they are expanded to {-1,0,+1} int8 values inside VMEM, right next
-to the MXU, and consumed immediately — the exact analogue of TLUT/TGEMV
-building and consuming tables inside the SIMD register file instead of DRAM.
+The TPU realization of the paper's in-register dataflow: the 2-bit weight
+bitplanes are the ONLY weight bytes that cross HBM; they are expanded to
+{-1,0,+1} int8 values inside VMEM, right next to the MXU, and consumed
+immediately — the analogue of TLUT/TGEMV building and consuming tables
+inside the SIMD register file instead of DRAM.  The activations arrive in
+the bit-major channel order of :func:`deinterleave`, so the decode needs no
+in-kernel reshape.
+
+The serving engine's jitted step does not call this kernel: its packed
+layers run the jnp planes spelling in ``models.layers._packed_linear``,
+which computes the same integers.  This kernel runs when called through
+``kernels.ops`` (or a registry lowering with ``use_pallas``).
 
 Dataflow (paper Sec. III-D) maps to the grid iteration order:
 
@@ -30,12 +37,42 @@ from jax.experimental.pallas import tpu as pltpu
 PACK = 8
 
 
-def _unpack_plane(plane: jax.Array, bk: int) -> jax.Array:
-    """(bk//8, bm) uint8 -> (bk, bm) int8 {0,1}, LSB-first (matches
-    repro.core.ternary._pack_bits)."""
-    shifts = jnp.arange(PACK, dtype=jnp.uint8)[None, :, None]
-    bits = (plane[:, None, :] >> shifts) & jnp.uint8(1)
-    return bits.reshape(bk, plane.shape[-1]).astype(jnp.int8)
+def deinterleave(a: jax.Array, tile: int, group: int) -> jax.Array:
+    """Reorder each ``tile``-column tile of ``a`` (N, K) from channel order
+    ``group*j + i`` to ``i * (tile//group) + j``.
+
+    A packed weight row mixes ``group`` neighbouring channels: bit ``i`` of
+    plane byte ``j`` holds channel ``8*j + i`` (LSB-first, as
+    ``repro.core.ternary._pack_bits`` writes it), and bit ``i`` of LUT index
+    ``j`` holds channel ``c*j + i``.  With the activations in this order, a
+    kernel reads the channels that pair with bit ``i`` as one contiguous,
+    lane-aligned slice, so it needs no lane or sublane reshape, which Mosaic
+    refuses.  The permutation only changes the order of a sum.
+    """
+    n, k = a.shape
+    return (a.reshape(n, k // tile, tile // group, group)
+            .swapaxes(2, 3).reshape(n, k))
+
+
+def decode_tile(sign: jax.Array, zero: jax.Array) -> jax.Array:
+    """(bk//8, bm) uint8 sign/zero planes -> (bk, bm) int8 in {-1, 0, +1},
+    rows in the bit-major order of :func:`deinterleave` (group 8)."""
+    s = sign.astype(jnp.int32)
+    z = zero.astype(jnp.int32)
+    groups = [(1 - 2 * ((s >> i) & 1)) * (1 - ((z >> i) & 1))
+              for i in range(PACK)]
+    return jnp.concatenate(groups, axis=0).astype(jnp.int8)
+
+
+def vmem_bytes(bn: int, bk: int, bm: int) -> int:
+    """VMEM one grid step of :func:`tsar_matmul_packed` needs: its in/out
+    blocks, double-buffered (a (bn, 1) column pads to 128 lanes, a (1, bm)
+    row to 8 sublanes), the int32 accumulator, and the decoded tile (int32
+    bit groups, then the int8 stack).  Compare with the chip's scoped VMEM
+    limit (``repro.core.hw``), not its whole VMEM."""
+    blocks = (bn * bk + 2 * (bk // PACK) * bm + bn * 128 * 4 + 8 * bm * 4
+              + bn * bm * 4)
+    return 2 * blocks + bn * bm * 4 + bk * bm * 5 + 2 * (bk // PACK) * bm * 4
 
 
 def _kernel(a_ref, sign_ref, zero_ref, asc_ref, wsc_ref, o_ref, acc_ref, *,
@@ -47,11 +84,7 @@ def _kernel(a_ref, sign_ref, zero_ref, asc_ref, wsc_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bk = a_ref.shape[-1]
-    sign = _unpack_plane(sign_ref[...], bk)   # 1 => weight < 0
-    zero = _unpack_plane(zero_ref[...], bk)   # 1 => weight == 0
-    # vals = (1 - 2*sign) * (1 - zero) in {-1, 0, +1}
-    vals = ((1 - 2 * sign) * (1 - zero)).astype(jnp.int8)
+    vals = decode_tile(sign_ref[...], zero_ref[...])
     acc_ref[...] += jax.lax.dot_general(
         a_ref[...], vals,
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -135,5 +168,6 @@ def tsar_matmul_packed(
         out_shape=jax.ShapeDtypeStruct((n, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, bm), jnp.int32)],
         interpret=interpret,
-    )(a_q, sign_plane, zero_plane, a_scale, w_scale.reshape(1, m))
+    )(deinterleave(a_q, bk, PACK), sign_plane, zero_plane, a_scale,
+      w_scale.reshape(1, m))
     return out

@@ -40,7 +40,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Same in-VMEM bitplane decode as the dense kernel — one definition, so the
 # two kernels can't drift from core/ternary._pack_bits's LSB-first layout.
-from repro.kernels.tsar_matmul import PACK, _unpack_plane
+from repro.kernels.tsar_matmul import PACK, decode_tile, deinterleave
 
 
 def _kernel(kids_ref, slots_ref, counts_ref, a_ref, sign_ref, zero_ref,
@@ -55,10 +55,7 @@ def _kernel(kids_ref, slots_ref, counts_ref, a_ref, sign_ref, zero_ref,
 
     @pl.when(s < counts_ref[j])
     def _accumulate():
-        bk = a_ref.shape[-1]
-        sign = _unpack_plane(sign_ref[0], bk)   # 1 => weight < 0
-        zero = _unpack_plane(zero_ref[0], bk)   # 1 => weight == 0
-        vals = ((1 - 2 * sign) * (1 - zero)).astype(jnp.int8)
+        vals = decode_tile(sign_ref[0], zero_ref[0])
         acc_ref[...] += jax.lax.dot_general(
             a_ref[...], vals,
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -124,7 +121,8 @@ def tsar_sparse_matmul_packed(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, mb * bm), jnp.float32),
         interpret=interpret,
-    )(kids, slots, counts, a_q, sign_pool, zero_pool, a_scale, w_scale)
+    )(kids, slots, counts, deinterleave(a_q, bk, PACK), sign_pool, zero_pool,
+      a_scale, w_scale)
     return out
 
 
@@ -149,10 +147,7 @@ def _kernel_2d(kids_ref, slots_ref, counts_ref, act_live_ref, a_ref, sign_ref,
 
     @pl.when(live)
     def _accumulate():
-        bk = a_ref.shape[-1]
-        sign = _unpack_plane(sign_ref[0], bk)   # 1 => weight < 0
-        zero = _unpack_plane(zero_ref[0], bk)   # 1 => weight == 0
-        vals = ((1 - 2 * sign) * (1 - zero)).astype(jnp.int8)
+        vals = decode_tile(sign_ref[0], zero_ref[0])
         acc_ref[...] += jax.lax.dot_general(
             a_ref[...], vals,
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -225,6 +220,6 @@ def tsar_sparse_padded_matmul_packed(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, mb * bm), jnp.float32),
         interpret=interpret,
-    )(kids, slots, counts, act_live, a_q, sign_pool, zero_pool, a_scale,
-      w_scale)
+    )(kids, slots, counts, act_live, deinterleave(a_q, bk, PACK), sign_pool,
+      zero_pool, a_scale, w_scale)
     return out

@@ -44,12 +44,9 @@ BIG_PARAMS = 60e9  # above this, bf16 adam moments (fits 400B on one pod)
 
 
 def cost_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions: newer jax
-    returns one flat dict, older returns a per-device list of dicts."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``compiled.cost_analysis()``: one flat dict (empty when the backend
+    reports nothing)."""
+    return compiled.cost_analysis() or {}
 
 
 def _named(mesh, specs):

@@ -19,9 +19,14 @@ import json
 import re
 from dataclasses import asdict, dataclass
 
-# TPU v5e hardware constants (per chip) — shared with core/dataflow via
-# core/hw so the dispatch cost model and the dry-run roofline can't drift.
-from repro.core.hw import HBM_BW, ICI_LINK_BW, PEAK_FLOPS_BF16  # noqa: F401
+from repro.core import hw
+
+# The dry-run compiles for TPU v5e meshes, so its roofline names that chip's
+# entry of the shared peak table (core/hw) rather than the host's device.
+_V5E = hw.PEAKS["TPU v5 lite"]
+PEAK_FLOPS_BF16 = _V5E.bf16_flops
+HBM_BW = _V5E.hbm_bw
+ICI_LINK_BW = _V5E.ici_link_bw
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

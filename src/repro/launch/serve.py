@@ -34,12 +34,13 @@ import numpy as np
 import jax
 
 import repro.configs as configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_zoo as zoo
 from repro.obs import export as obs_export
 from repro.obs.incident import IncidentMonitor
 from repro.obs.trace import EventTracer, MemorySink, RingSink, StreamingSink, TeeSink
 from repro.plan import ModelPlan, format_plan
-from repro.serving import Request, ServingEngine
+from repro.serving import Request, ServingEngine, init_packed_params
 
 
 def _print_percentiles(engine) -> None:
@@ -123,6 +124,13 @@ def _obs_finish(args, obs: dict) -> None:
                   file=sys.stderr)
 
 
+def _init_params(cfg, packed: bool) -> dict:
+    """Random weights from seed 0: frozen to 2-bit planes inside the init
+    program when serving packed, so full widths fit on one chip."""
+    key = jax.random.PRNGKey(0)
+    return init_packed_params(cfg, key) if packed else zoo.init_params(cfg, key)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -183,6 +191,7 @@ def main():
                     metavar="SECONDS",
                     help="rewrite interval for --metrics-textfile")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.workload or args.trace_file:
         return serve_workload(args)
@@ -190,7 +199,7 @@ def main():
     cfg = configs.get(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-    params = zoo.init_params(cfg, jax.random.PRNGKey(0))
+    params = _init_params(cfg, packed=not args.no_packed)
     plan = None
     if args.plan_file and os.path.exists(args.plan_file):
         plan = ModelPlan.load(args.plan_file)
@@ -274,7 +283,7 @@ def serve_workload(args):
     cfg = configs.get(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-    params = zoo.init_params(cfg, jax.random.PRNGKey(0))
+    params = _init_params(cfg, packed=not args.no_packed)
     obs = _obs_setup(args)
     engine = runner.build_engine(spec, cfg, params,
                                  packed=not args.no_packed,

@@ -52,26 +52,29 @@ def _packed_linear(p: dict, x: jax.Array) -> jax.Array:
     When a ``ModelPlan`` is active (the serving engine activates its plan
     around every jitted step) the planned kernel for this layer's (k, m) at
     the step's token count decides the realization — a trace-time constant
-    table lookup, never a ``select_kernel`` call.  Off-TPU the dense T-SAR
-    kernel families realize as the same exact decode->int8-dot spelling
-    below (the Pallas grids differ on TPU, the integer math does not), so
-    planned ``tsar_mxu``/``tsar_lut`` are bit-identical here; a planned
-    ``tsar_sparse_padded`` runs the registry lowering over the layer's
-    ``sp_*`` padded-pool leaves — the weights decoded in the jitted step
-    come FROM THE POOL (vmap-stacked per scan layer), bit-identical to the
-    planes decode because the pool round-trips exactly; and the baselines
-    genuinely switch: planned ``dense`` runs the dequantized fp matmul and
-    planned ``memory_lut`` the DRAM-LUT gather (both via the registry
-    lowering), so A/B plans measure what their label says.  A planned
-    ``tsar_sparse`` (compacted — unserveable from a params tree, its pool
-    size is data-dependent) degrades to the padded lowering when the leaves
-    are present, else to the planes spelling — same math either way.
+    table lookup, never a ``select_kernel`` call.
 
-    The only weight bytes read are the two uint8 bitplanes (+ per-channel
-    scales): this is what makes the serve-path HBM traffic 8x smaller than
-    bf16 and what the dry-run roofline measures.  On TPU the same math runs
-    in the fused Pallas kernel (repro.kernels); this jnp spelling lowers to
-    the identical decode->MXU dataflow and is SPMD-shardable.
+    On every backend, TPU included, a planned ``tsar_mxu`` or ``tsar_lut``
+    runs the jnp spelling below: unpack the planes to int8, then one
+    int8 x int8 -> int32 dot.  No Pallas kernel is called (their
+    ``serve_via_registry`` is False), so with planes-only params the
+    served step's HLO holds no ``tpu_custom_call``; the Pallas kernels (``repro.kernels``) compute the
+    same integers and run when called directly.  Whether the step should
+    call them is for a chip trace to decide.  A planned
+    ``tsar_sparse_padded`` runs the registry lowering over the layer's
+    ``sp_*`` padded-pool leaves: the 2-D zero-skip Pallas kernel on a TPU,
+    elsewhere a decode from the pool, bit-identical to the planes.  The
+    baselines genuinely switch: planned ``dense`` runs the
+    dequantized fp matmul and planned ``memory_lut`` the DRAM-LUT gather,
+    so A/B plans measure what their label says.  A planned ``tsar_sparse``
+    (compacted — unserveable from a params tree, its pool size is
+    data-dependent) degrades to the padded lowering when the leaves are
+    present, else to the planes spelling — same math either way.
+
+    The only weight bytes stored are the two uint8 bitplanes (+
+    per-channel scales), 8x fewer than bf16; whether XLA keeps the decoded
+    int8 weights out of HBM is not measured yet.  The spelling is
+    SPMD-shardable.
     """
     from repro.plan import runtime as plan_runtime
 

@@ -53,8 +53,9 @@ def _init_block(key, cfg) -> dict:
 def init_params(cfg, key) -> dict:
     kl, ke, kh, kf = jax.random.split(key, 4)
     layer_keys = jax.random.split(kl, cfg.n_layers)
-    blocks = [_init_block(k, cfg) for k in layer_keys]
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)
+    # vmap draws the same values as one _init_block call per key, stacked,
+    # without holding the per-layer copies beside the stack.
+    stacked = jax.vmap(lambda k: _init_block(k, cfg))(layer_keys)
     params = {
         "blocks": stacked,
         "embed": jax.random.normal(ke, (cfg.padded_vocab, cfg.d_model), jnp.float32) * 0.02,

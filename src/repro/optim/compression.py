@@ -37,10 +37,7 @@ def _allreduce_int8(q: jax.Array, scale: jax.Array, axis: str) -> jax.Array:
 
     Returns the dequantized mean (f32), same shape as q.
     """
-    # psum of a static 1 folds to the concrete axis size (works on jax
-    # versions without jax.lax.axis_size, and stays a Python int so the
-    # reshape below keeps static shapes).
-    n = jax.lax.psum(1, axis)
+    n = jax.lax.axis_size(axis)   # a Python int: the reshapes stay static
     flat = q.reshape(-1)
     pad = (-flat.shape[0]) % n
     flat = jnp.pad(flat, (0, pad))

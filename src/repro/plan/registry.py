@@ -174,17 +174,17 @@ class TsarMXU:
 
     def cost(self, n, k, m, c=4, density=DEFAULT_DENSITY, block_density=None,
              block_shape=SPARSE_BLOCK):
-        hw = _hw()
+        pk = _hw().chip_peaks()
         flops = 2.0 * n * k * m                      # int8 MACs on the MXU
         decode_ops = k * m * 4.0                     # bitplane unpack ALU ops
-        compute = flops / hw.PEAK_FLOPS_INT8 + decode_ops / (hw.PEAK_FLOPS_INT8 / 2)
+        compute = flops / pk.int8_ops + decode_ops / (pk.int8_ops / 2)
         bytes_moved = (
             k * m * 0.25                             # 2-bit packed weights
             + n * k * 1.0                            # int8 activations
             + n * m * 2.0                            # bf16 outputs
             + m * 4.0                                # scales
         )
-        return compute, bytes_moved / hw.HBM_BW
+        return compute, bytes_moved / pk.hbm_bw
 
     def supports(self, frozen):
         return has_planes(frozen)
@@ -216,20 +216,20 @@ class TsarLUT:
 
     def cost(self, n, k, m, c=4, density=DEFAULT_DENSITY, block_density=None,
              block_shape=SPARSE_BLOCK):
-        hw = _hw()
+        pk = _hw().chip_peaks()
         blocks = k / c
         lut_build = n * blocks * (2 ** c) * 1.0      # TLUT expansion ops
         # Each gather lowered as one-hot x LUT: 2^c MACs per (block, m) pair,
         # two gathers per block (pos/zero) fused into one 2^c-wide matmul.
         gather = 2.0 * n * blocks * m * (2 ** c) / 8.0
-        compute = (lut_build + gather) / hw.PEAK_FLOPS_INT8
+        compute = (lut_build + gather) / pk.int8_ops
         bytes_moved = (
             2.0 * (k / c) * m * 1.0                  # idx_pos + idx_zero, 1B each
             + n * k * 1.0
             + n * m * 2.0
             + m * 4.0
         )
-        return compute, bytes_moved / hw.HBM_BW
+        return compute, bytes_moved / pk.hbm_bw
 
     def supports(self, frozen):
         return _leaf(frozen, "idx_pos") is not None
@@ -271,6 +271,7 @@ class TsarSparse:
         index map (int32 per block) and per-strip gather lists are the
         sparsity tax, which is why the dense kernel wins at density ~ 1."""
         hw = _hw()
+        pk = hw.chip_peaks()
         tax = hw.sparse_issue_tax()
         if block_density is None:
             block_density = estimate_block_density(density, block_shape)
@@ -280,7 +281,7 @@ class TsarSparse:
         flops = 2.0 * n * bk * bm * live             # int8 MACs, live blocks only
         decode_ops = bk * bm * live * 4.0            # bitplane unpack, live only
         compute = tax * (
-            flops / hw.PEAK_FLOPS_INT8 + decode_ops / (hw.PEAK_FLOPS_INT8 / 2))
+            flops / pk.int8_ops + decode_ops / (pk.int8_ops / 2))
         bytes_moved = (
             tax * live * bk * bm * 0.25              # 2-bit planes, live blocks
             + kb * mb * 4.0                          # block-index map (int32)
@@ -289,7 +290,7 @@ class TsarSparse:
             + n * m * 2.0                            # bf16 outputs
             + m * 4.0                                # scales
         )
-        return compute, bytes_moved / hw.HBM_BW
+        return compute, bytes_moved / pk.hbm_bw
 
     def supports(self, frozen):
         return _leaf(frozen, "sparse") is not None
@@ -371,13 +372,14 @@ class TsarSparsePadded(TsarSparse):
                                     block_density=block_density,
                                     block_shape=block_shape)
         hw = _hw()
+        pk = hw.chip_peaks()
         if block_density is None:
             block_density = estimate_block_density(density, block_shape)
         bk, bm = block_shape
         kb, mb = max(k / bk, 1.0), max(m / bm, 1.0)
         dead = (1.0 - block_density) * kb * mb
-        per_block = (2.0 * n * bk * bm / hw.PEAK_FLOPS_INT8
-                     + bk * bm * 4.0 / (hw.PEAK_FLOPS_INT8 / 2))
+        per_block = (2.0 * n * bk * bm / pk.int8_ops
+                     + bk * bm * 4.0 / (pk.int8_ops / 2))
         comp += hw.sparse_pad_step_frac() * dead * per_block
         return comp, mem
 
@@ -420,15 +422,15 @@ class MemoryLUT:
 
     def cost(self, n, k, m, c=4, density=DEFAULT_DENSITY, block_density=None,
              block_shape=SPARSE_BLOCK):
-        hw = _hw()
+        pk = _hw().chip_peaks()
         blocks = k / c
-        compute = 2.0 * n * blocks * m / hw.PEAK_FLOPS_INT8
+        compute = 2.0 * n * blocks * m / pk.int8_ops
         bytes_moved = (
             n * blocks * (3 ** c) * 4.0              # DRAM-resident LUT tables
             + blocks * m * 1.0                       # index stream
             + n * k * 1.0 + n * m * 2.0 + m * 4.0
         )
-        return compute, bytes_moved / hw.HBM_BW
+        return compute, bytes_moved / pk.hbm_bw
 
     def supports(self, frozen):
         return has_planes(frozen)
@@ -461,10 +463,10 @@ class Dense:
 
     def cost(self, n, k, m, c=4, density=DEFAULT_DENSITY, block_density=None,
              block_shape=SPARSE_BLOCK):
-        hw = _hw()
-        compute = 2.0 * n * k * m / hw.PEAK_FLOPS_BF16
+        pk = _hw().chip_peaks()
+        compute = 2.0 * n * k * m / pk.bf16_flops
         bytes_moved = k * m * 2.0 + n * k * 2.0 + n * m * 2.0
-        return compute, bytes_moved / hw.HBM_BW
+        return compute, bytes_moved / pk.hbm_bw
 
     def supports(self, frozen):
         return has_planes(frozen)

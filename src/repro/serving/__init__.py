@@ -2,6 +2,7 @@ from repro.serving.engine import (  # noqa: F401
     Request,
     ServingEngine,
     freeze_params,
+    init_packed_params,
     packed_fraction,
 )
 from repro.serving.kv_cache import PagedKVCache  # noqa: F401

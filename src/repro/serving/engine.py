@@ -244,6 +244,24 @@ def freeze_params(params, *, sparse: str | bool = "auto",
     return walk(params)
 
 
+def init_packed_params(cfg, key) -> dict:
+    """``freeze_params(model_zoo.init_params(cfg, key), sparse=False)`` as
+    one jitted program, so the latent float32 tree never lives on the
+    device beside the frozen one.
+
+    At bitnet-2b-4t's widths the latent tree alone takes 11 GB, and freezing
+    it eagerly needs more than one 16 GB v5e holds; compiled for v5e, this
+    program needs 3.2 GB of output and 4.2 GB of temporaries.  It draws the
+    same random values: the 2-bit planes equal the eager ones bit for bit,
+    while float leaves (embeddings, head, scales) may differ in the last
+    bits, where XLA fuses their arithmetic differently.  Traced weights are
+    not measured, so no padded sparse pools are emitted (``sparse="auto"``
+    emits none for random weights either).
+    """
+    return jax.jit(lambda k: freeze_params(model_zoo.init_params(cfg, k),
+                                           sparse=False))(key)
+
+
 def density_telemetry(params) -> dict | None:
     """Per-layer weight-density profile of a packed params tree (host-side).
 

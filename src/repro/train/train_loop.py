@@ -12,7 +12,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models import model_zoo
 from repro.optim import OptConfig, adamw_init, adamw_update, compression
@@ -129,10 +128,10 @@ def make_compressed_dp_train_step(cfg, opt_cfg: OptConfig, mesh, *, remat: bool 
         state_spec = jax.tree.map(lambda _: replicated, state)
         # batch leaves are (B, ...): shard B over the DP axis.
         batch_spec = jax.tree.map(lambda x: P(axis, *([None] * (x.ndim - 1))), batch)
-        fn = shard_map(step, mesh=mesh,
-                       in_specs=(state_spec, batch_spec),
-                       out_specs=(state_spec, replicated),
-                       check_rep=False)
+        fn = jax.shard_map(step, mesh=mesh,
+                           in_specs=(state_spec, batch_spec),
+                           out_specs=(state_spec, replicated),
+                           check_vma=False)
         return fn(state, batch)
 
     return jax.jit(wrapped)

@@ -8,7 +8,7 @@ analysis, and the engine wiring contracts:
 * tracing ON yields a deterministic event *structure* — same-seed replays
   produce identical structure fingerprints (wall clock lives only in
   ts/dur), and every request's span sequence is well-formed
-  (property-tested via the hypothesis shim);
+  (property-tested with hypothesis);
 * ``reset_run_stats`` REBASES peak gauges to current state instead of
   zeroing them (the satellite fix pinned here);
 * per-machine SLO calibration scales ``is_good`` thresholds and is recorded
@@ -19,7 +19,7 @@ import math
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.obs import NULL_TRACER, MetricsRegistry, StatsView
 from repro.obs import timeline

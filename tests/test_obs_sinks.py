@@ -6,7 +6,7 @@ the metrics export surface (PR 10):
   a bounded number of events resident, and truncates on ``reset()`` so
   warm-up never leaks into a saved stream;
 * ``timeline`` analyzes the JSONL stream to exactly the document analysis
-  (property-tested via the hypothesis shim), and its CLI fails a
+  (property-tested with hypothesis), and its CLI fails a
   ``--min-step-utilization`` gate on a zero-step trace with a clear
   message instead of silently passing;
 * ``repro.obs.export`` renders the registry so a scrape matches
@@ -23,7 +23,7 @@ import urllib.request
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.obs import timeline
 from repro.obs import trace as obs_trace
@@ -315,7 +315,7 @@ class TestTimelineStream:
         assert "n/a" in timeline.format_summary(s)
 
 
-# -- hypothesis-shim property: stream == memory for arbitrary sequences ------
+# -- hypothesis property: stream == memory for arbitrary sequences ------
 
 class TestStreamProperty:
     @settings(max_examples=15, deadline=None)

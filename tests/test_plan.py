@@ -696,3 +696,25 @@ class TestSparseServing:
             if "sp_sign" in leaf:
                 assert leaf["sp_sign"].shape[1] >= live_floor
                 assert leaf["sp_kids"].shape[-1] >= step_floor
+
+
+def test_chip_peaks_keyed_by_device_kind(monkeypatch):
+    """Planning reads the peaks of its own TPU kind, refuses a kind the
+    table lacks, and off the TPU names the v5e entry."""
+    import types
+
+    from repro.core import hw
+
+    hw.chip_peaks.cache_clear()
+    try:
+        assert hw.chip_peaks() is hw.PEAKS[hw.OFF_TPU_KIND]   # CPU here
+        dev = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+        monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+        hw.chip_peaks.cache_clear()
+        assert hw.chip_peaks().int8_ops == 393e12
+        dev.device_kind = "TPU v9 unknown"
+        hw.chip_peaks.cache_clear()
+        with pytest.raises(ValueError, match="TPU v9 unknown"):
+            compile_plan({"proj": {"w": jnp.ones((256, 256))}})
+    finally:
+        hw.chip_peaks.cache_clear()

@@ -93,7 +93,6 @@ class TestGradCompression:
 
     def test_compressed_psum_single_device(self):
         """shard_map over a 1-device mesh: compression must be ~lossless-mean."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = jax.make_mesh((1,), ("data",))
@@ -103,9 +102,9 @@ class TestGradCompression:
         def f(g, e):
             return compression.psum_compressed(g, e, "data")
 
-        out, new_err = jax.jit(shard_map(
+        out, new_err = jax.jit(jax.shard_map(
             f, mesh=mesh,
-            in_specs=(P(), P()), out_specs=(P(), P()), check_rep=False,
+            in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False,
         ))(grads, err)
         np.testing.assert_allclose(np.asarray(out["w"]),
                                    np.asarray(grads["w"]), rtol=2e-2, atol=2e-2)

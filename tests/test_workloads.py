@@ -5,9 +5,12 @@ declared shared-prefix structure, and trace serialization round-trips.
 These are generator-only tests (no engine, no jax) — the replay integration
 lives in ``tests/test_bench_report.py``.
 """
+import os
+import tempfile
+
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from benchmarks.workloads import generator
 from benchmarks.workloads.generator import WorkloadSpec, generate, preset
@@ -180,11 +183,14 @@ class TestTraceIO:
     @settings(max_examples=6, deadline=None)
     @given(name=st.sampled_from(PRESETS),
            seed=st.integers(min_value=0, max_value=2**20))
-    def test_save_load_roundtrip(self, tmp_path, name, seed):
+    def test_save_load_roundtrip(self, name, seed):
+        # A directory per example: a function-scoped tmp_path would be
+        # shared by every example hypothesis draws.
         tr = generate(preset(name, quick=True, seed=seed))
-        p = tmp_path / "trace.json"
-        tr.save(str(p))
-        tr2 = Trace.load(str(p))
+        with tempfile.TemporaryDirectory() as d:
+            p = os.path.join(d, "trace.json")
+            tr.save(p)
+            tr2 = Trace.load(p)
         assert tr2.to_json() == tr.to_json()
         assert tr2.fingerprint() == tr.fingerprint()
 
