@@ -170,9 +170,10 @@ def main():
                          "JSON timeline (python -m repro.obs.timeline PATH "
                          "to analyze; docs/observability.md)")
     ap.add_argument("--profile-steps", action="store_true",
-                    help="wrap each jitted engine step in a jax.profiler "
-                         "StepTraceAnnotation so XLA device traces align "
-                         "with engine steps")
+                    help="mark each engine step and its host phases "
+                         "(admit, plan, dispatch, wait, sample, emit) with "
+                         "jax.profiler spans so XLA device traces align "
+                         "with them (docs/observability.md)")
     ap.add_argument("--trace-stream", default=None, metavar="PATH",
                     help="stream trace events to a rotated JSONL file "
                          "(bounded memory; OBS_TRACE_STREAM schema v1) — "

@@ -45,6 +45,7 @@ def linear(p: dict, x: jax.Array, train: bool = True) -> jax.Array:
     raise ValueError(f"unrecognized linear params: {list(p)}")
 
 
+@jax.named_scope("bitlinear")
 def _packed_linear(p: dict, x: jax.Array) -> jax.Array:
     """Inference forward from 2-bit planes, dispatched through the active
     execution plan (``repro.plan.runtime``).
@@ -246,6 +247,7 @@ def _split_heads(x, n_heads, dh):
     return x.reshape(x.shape[:-1] + (n_heads, dh))
 
 
+@jax.named_scope("attention")
 def attention(
     cfg,
     p: dict,

@@ -102,6 +102,7 @@ def init_paged_cache(cfg, slots: int, num_blocks: int, block_size: int,
     return pools
 
 
+@jax.named_scope("kv_gather")
 def gather_cache_view(pools: dict, block_table) -> dict:
     """Materialize a contiguous per-slot cache view through block tables.
 
@@ -122,6 +123,7 @@ def gather_cache_view(pools: dict, block_table) -> dict:
     return view
 
 
+@jax.named_scope("kv_scatter")
 def scatter_cache_view(pools: dict, block_table, view: dict) -> dict:
     """Write an updated contiguous view back into the block pools.
 

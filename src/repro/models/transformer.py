@@ -173,6 +173,7 @@ def _embed_inputs(cfg, params, batch, train):
     return x.astype(jnp.float32)
 
 
+@jax.named_scope("head")
 def _head(cfg, params, x):
     from repro.utils.act_sharding import constrain
 

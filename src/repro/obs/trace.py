@@ -751,17 +751,32 @@ def load_any(path: str) -> tuple:
 
 
 # ---------------------------------------------------------------------------
-# optional jax.profiler alignment hooks
+# jax.profiler alignment: names on the profiler's clock
 # ---------------------------------------------------------------------------
 
-def step_annotation(step_num: int):
-    """Context manager annotating one engine step in an XLA profiler trace
-    (``jax.profiler.StepTraceAnnotation``), so device timelines captured
-    with ``jax.profiler.trace(...)`` align with engine-step records.  Falls
-    back to a null context when the profiler is unavailable."""
-    try:
-        from jax import profiler
-        return profiler.StepTraceAnnotation("tsar_engine_step",
-                                            step_num=step_num)
-    except Exception:
-        return contextlib.nullcontext()
+# Device scopes: ``jax.named_scope`` around the parts of the jitted serving
+# step, always in the compiled program's ``op_name`` metadata.  An op
+# belongs to the innermost of these names on its path (a BitLinear inside
+# attention is ``bitlinear``); an op under none of them is ``other``.
+STEP_SCOPES = ("kv_gather", "kv_scatter", "attention", "bitlinear", "head")
+# Host spans of ``ServingEngine.step`` (with ``profiler_annotations``).
+# ``engine.dispatch`` and ``engine.wait`` sit inside ``STEP_SPAN``, which
+# covers the jitted call; the others are its siblings.
+STEP_SPAN = "tsar_engine_step"
+ENGINE_SPANS = ("engine.admit", "engine.plan", "engine.dispatch",
+                "engine.wait", "engine.sample", "engine.emit")
+NULL_SPAN = contextlib.nullcontext()
+
+
+def profiler_span(name: str, step_num: int):
+    """A span named ``name`` on the ``jax.profiler`` clock, tagged with the
+    engine's ``step_num``, so a device trace captured with
+    ``jax.profiler.trace(...)`` lines up with the engine's steps and host
+    phases.  ``STEP_SPAN`` is the profiler's step marker
+    (``StepTraceAnnotation``).  Call sites guard on their own flag and
+    enter the shared ``NULL_SPAN`` when it is off."""
+    from jax import profiler
+
+    if name == STEP_SPAN:
+        return profiler.StepTraceAnnotation(name, step_num=step_num)
+    return profiler.TraceAnnotation(name, step_num=step_num)

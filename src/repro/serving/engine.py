@@ -40,7 +40,6 @@ Weight modes:
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import time
 import warnings
@@ -755,8 +754,16 @@ class ServingEngine:
 
     def step(self) -> bool:
         """One engine step: admit, then one mixed prefill-chunk/decode call.
-        Returns False when there was nothing to do."""
-        self._admit()
+        Returns False when there was nothing to do.
+
+        With ``profiler_annotations`` each host phase is a span on the
+        profiler's clock (``obs.trace.ENGINE_SPANS``): admit, plan, the
+        dispatch and the wait inside ``tsar_engine_step``, sample, emit."""
+        span, off = obs_trace.profiler_span, obs_trace.NULL_SPAN
+        prof = self._profile_steps
+        step_no = self._c_steps.value
+        with span("engine.admit", step_no) if prof else off:
+            self._admit()
         flat = self.policy == "flat"
 
         def _plan():
@@ -765,36 +772,35 @@ class ServingEngine:
                                             self.token_budget)
             return self.sched.plan(self._slots, self.kv)
 
-        plan = _plan()
-        while isinstance(plan, Preempt):
-            self._preempt(plan.slot)
+        with span("engine.plan", step_no) if prof else off:
             plan = _plan()
-        if plan is None:
-            return False
-
-        table = self.kv.table_view(plan.view_blocks)
-        step_no = self._c_steps.value
+            while isinstance(plan, Preempt):
+                self._preempt(plan.slot)
+                plan = _plan()
+            if plan is None:
+                return False
+            table = self.kv.table_view(plan.view_blocks)
         # planned = the static step width: the rows the jitted matmuls
         # actually multiply (flat: T; rectangular: the padded B*C).
         # realized/planned is the step-budget utilization the timeline CLI
         # reports; 1 - it is exactly the padding waste the flat layout
         # removes.
         planned = plan.width if flat else self.slots * plan.chunk
-        ann = (obs_trace.step_annotation(step_no) if self._profile_steps
-               else contextlib.nullcontext())
         t0 = time.perf_counter()
-        with ann:
-            if flat:
-                sel, self.kv.pools = self._flat_fn(
-                    self.params, self.kv.pools, table,
-                    jnp.asarray(plan.tokens), jnp.asarray(plan.slot),
-                    jnp.asarray(plan.pos), jnp.asarray(plan.emit_row))
-            else:
-                sel, self.kv.pools = self._chunk_fn(
-                    self.params, self.kv.pools, table,
-                    jnp.asarray(plan.tokens), jnp.asarray(plan.pos),
-                    jnp.asarray(plan.lengths), jnp.asarray(plan.emit_idx))
-            sel.block_until_ready()
+        with span(obs_trace.STEP_SPAN, step_no) if prof else off:
+            with span("engine.dispatch", step_no) if prof else off:
+                if flat:
+                    sel, self.kv.pools = self._flat_fn(
+                        self.params, self.kv.pools, table,
+                        jnp.asarray(plan.tokens), jnp.asarray(plan.slot),
+                        jnp.asarray(plan.pos), jnp.asarray(plan.emit_row))
+                else:
+                    sel, self.kv.pools = self._chunk_fn(
+                        self.params, self.kv.pools, table,
+                        jnp.asarray(plan.tokens), jnp.asarray(plan.pos),
+                        jnp.asarray(plan.lengths), jnp.asarray(plan.emit_idx))
+            with span("engine.wait", step_no) if prof else off:
+                sel.block_until_ready()
         dt = time.perf_counter() - t0
 
         self._c_steps.inc()
@@ -824,10 +830,19 @@ class ServingEngine:
 
         toks = None
         if plan.emit.any():
-            temps = np.array([
-                self._slots[i].req.temperature if plan.emit[i] else 0.0
-                for i in range(self.slots)], np.float32)
-            toks = self._sample(sel, temps)
+            with span("engine.sample", step_no) if prof else off:
+                temps = np.array([
+                    self._slots[i].req.temperature if plan.emit[i] else 0.0
+                    for i in range(self.slots)], np.float32)
+                toks = self._sample(sel, temps)
+        with span("engine.emit", step_no) if prof else off:
+            self._emit_step(plan, toks)
+        return True
+
+    def _emit_step(self, plan, toks):
+        """Land one step's tokens: advance each slot, emit, register
+        prefixes, then the prefix stats and the incident tick."""
+        tr = self.tracer
         for i in range(self.slots):
             st = self._slots[i]
             if st is None or plan.n_real[i] == 0:
@@ -859,7 +874,6 @@ class ServingEngine:
             self.incidents.step_tick(
                 evictions=max(0, ev - self._evictions_seen))
             self._evictions_seen = ev
-        return True
 
     def _preempt(self, i: int):
         """Recompute-style preemption (vLLM): return the youngest request to

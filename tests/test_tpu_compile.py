@@ -200,3 +200,39 @@ def test_packed_init_fits_one_chip(one_chip):
     assert mem.output_size_in_bytes > 3e9         # the real widths, not a cut
     assert mem.output_size_in_bytes + mem.temp_size_in_bytes \
         < 0.6 * V5E.hbm_bytes
+
+
+def test_flat_step_matmuls_carry_a_step_scope(one_chip):
+    """Compiled for v5e, every matmul of the flat step keeps its part's
+    name in its ``op_name`` (``obs.trace.STEP_SCOPES``, innermost wins),
+    and the KV copies keep theirs: a chip trace can split the step by
+    part."""
+    import re
+
+    from repro.obs.trace import STEP_SCOPES
+
+    cfg = configs.get("bitnet-2b-4t").reduced()
+    slots, t, view_blocks, block = 2, 10, 2, 16
+    params = jax.eval_shape(
+        lambda k: freeze_params(zoo.init_params(cfg, k), sparse=False),
+        jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: zoo.init_paged_cache(
+        cfg, slots, slots * view_blocks + 1, block))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    text = jax.jit(
+        lambda p, pools, tbl, tk, sl, ps, er:
+        _flat_call(cfg, p, pools, tbl, tk, sl, ps, er)).lower(
+        _specs(params, one_chip), _specs(pools, one_chip),
+        i32(slots, view_blocks), i32(t), i32(t), i32(t), i32(slots)).compile(
+    ).as_text()
+
+    def innermost(line):
+        m = re.search(r'op_name="([^"]*)"', line)
+        parts = m.group(1).split("/") if m else []
+        return next((p for p in reversed(parts) if p in STEP_SCOPES), None)
+
+    matmuls = [innermost(line) for line in text.splitlines()
+               if re.search(r" (convolution|dot)\(", line)]
+    assert len(matmuls) == 10     # q, k, v, o, gate, up, down, QK^T, PV, head
+    assert set(matmuls) == {"bitlinear", "attention", "head"}
+    assert {innermost(line) for line in text.splitlines()} >= set(STEP_SCOPES)
