@@ -257,7 +257,8 @@ def attention(
     is_global,                       # bool / 0-1 scalar; blends window mask
     kv_x: jax.Array | None = None,   # cross-attention source (B, T, D)
     causal: bool = True,
-    cache: dict | None = None,       # {'k','v'} (B, S_max, Hkv, Dh) decode cache
+    cache: dict | None = None,       # {'k','v'} (B, S_max, Hkv, Dh) decode
+                                     # cache; flat: the pools (see below)
     cache_len: jax.Array | None = None,  # valid prefix length (== pos of new tok)
     slot: jax.Array | None = None,   # (T,) per-token slot index (flat layout)
     train: bool = True,
@@ -292,34 +293,33 @@ def attention(
 
     new_cache = None
     if cache is not None and slot is not None:
-        # Flat token-packed decode (paged serving engine, ``flat`` policy):
-        # x is (1, T, D) — a ragged batch of T tokens from many slots packed
-        # along the sequence axis.  ``slot``/``pos`` are (T,) per-token
-        # coordinates into the (B, Vtok) cache view; padding rows carry the
-        # slot sentinel B.  Each token's K/V row is scattered to its own
-        # (slot, pos) cell; attention is segment-masked so a token sees
-        # exactly its own slot's causal prefix.
-        nb, vtok = cache["k"].shape[0], cache["k"].shape[1]
-        # Scatter by explicit flat index.  Padding rows are routed to a
-        # dump row appended past the live cells: JAX scatter DROPS
-        # out-of-bounds indices only in some modes and clamps in others, so
-        # the pad destination must be explicit, never "off the end".
-        widx = jnp.where(slot < nb, slot * vtok + pos, nb * vtok)
-
-        def flat_write(c, u):
-            flat = c.reshape((nb * vtok,) + c.shape[2:])
-            flat = jnp.concatenate([flat, jnp.zeros_like(flat[:1])], axis=0)
-            flat = flat.at[widx].set(u[0].astype(c.dtype))
-            return flat[:nb * vtok].reshape(c.shape)
-
-        ck = flat_write(cache["k"], k)
-        cv = flat_write(cache["v"], v)
-        new_cache = {"k": ck, "v": cv}
-        # Keys/values: the whole updated view flattened to one (B*Vtok,)
-        # key axis; the segment mask keeps cross-slot rows invisible.
-        k = ck.reshape((1, nb * vtok) + ck.shape[2:])
-        v = cv.reshape((1, nb * vtok) + cv.shape[2:])
+        # Flat token-packed step over the block pools (paged serving engine,
+        # ``flat`` policy): x is (1, T, D) — a ragged batch of T tokens from
+        # many slots packed along the sequence axis; ``slot``/``pos`` are
+        # (T,) per-token coordinates, padding rows carry the slot sentinel
+        # B.  ``cache`` holds the lane-dense pools (L, NB, bs, Hkv*Dh), this
+        # layer's index, the (B, VB) block table and each token's pool row
+        # (``blk``, ``off``).  The T new K/V rows are written in place
+        # first, so a token sees itself and its chunk's earlier rows; then
+        # this layer's (B, VB*bs) view is read through the table.
+        layer, table = cache["layer"], cache["table"]
+        nb, vb = table.shape
+        bs = cache["k"].shape[2]
+        with jax.named_scope("kv_scatter"):
+            at = (layer, cache["blk"], cache["off"])
+            pk = cache["k"].at[at].set(
+                k[0].reshape(s, hk * dh).astype(cache["k"].dtype))
+            pv = cache["v"].at[at].set(
+                v[0].reshape(s, hk * dh).astype(cache["v"].dtype))
+        new_cache = {"k": pk, "v": pv}
+        # Keys/values: every slot's view flattened to one (B*VB*bs,) key
+        # axis, slot-major; the segment mask keeps cross-slot rows
+        # invisible.
+        vtok = vb * bs
         t = nb * vtok
+        with jax.named_scope("kv_gather"):
+            k = pk[layer, table].reshape((1, t, hk, dh))
+            v = pv[layer, table].reshape((1, t, hk, dh))
         kidx = jnp.arange(t)
         kslot = kidx // vtok
         kpos = kidx % vtok

@@ -62,27 +62,33 @@ def chunk_step(cfg, params, tokens, pos, cache, lengths, train=False, plan=None)
                                     train)
 
 
-def flat_step(cfg, params, tokens, slot, pos, cache, emit_row, train=False,
-              plan=None):
+def flat_step(cfg, params, tokens, slot, pos, pools, table, emit_row,
+              train=False, plan=None):
     """Flat token-packed step (paged serving engine, ``flat`` policy):
     tokens/slot/pos (T,) per-token triples — multiple concurrent prefill
-    chunks plus all decode tokens in one call — and emit_row (B,) selecting
-    each slot's logit row before the head.  See transformer.flat_step."""
+    chunks plus all decode tokens in one call — run against the block pools
+    in place through the (B, VB) block ``table``, and emit_row (B,)
+    selecting each slot's logit row before the head.  See
+    transformer.flat_step."""
     with plan_runtime.activate(plan):
-        return _mod(cfg).flat_step(cfg, params, tokens, slot, pos, cache,
-                                   emit_row, train)
+        return _mod(cfg).flat_step(cfg, params, tokens, slot, pos, pools,
+                                   table, emit_row, train)
 
 
 # ---------------------------------------------------------------------------
 # Block-paged KV cache plumbing (serving engine)
 #
 # The attention K/V leaves ("k"/"v") are stored as a pool of fixed-size token
-# blocks, (L, num_blocks, block_size, Hkv, Dh); per-slot block tables map a
-# slot's logical token positions onto pool blocks.  Everything else (SSM
-# conv/state, enc-dec cross K/V) is O(1)-per-slot state and stays dense with a
-# leading slot axis.  Block 0 is a reserved scratch block: table padding
-# points at it, so gather/scatter of unallocated table entries read/write
-# garbage that the causal mask guarantees is never attended.
+# blocks, lane-dense: (L, num_blocks, block_size, Hkv*Dh).  Heads and head
+# dim share the last axis so the TPU tiles it whole (640 or 1024 lanes); a
+# (..., Hkv, Dh) pool puts Hkv on the sublanes, and XLA then copies the
+# whole pool into and out of any loop that writes it in place.  Per-slot
+# block tables map a slot's logical token positions onto pool blocks.
+# Everything else (SSM conv/state, enc-dec cross K/V) is O(1)-per-slot state
+# and stays dense with a leading slot axis.  Block 0 is a reserved scratch
+# block: table padding points at it, so reads and writes of unallocated
+# table entries touch garbage that the causal mask guarantees is never
+# attended.
 # ---------------------------------------------------------------------------
 
 PAGED_LEAVES = ("k", "v")
@@ -95,29 +101,31 @@ def init_paged_cache(cfg, slots: int, num_blocks: int, block_size: int,
     pools = {}
     for name, leaf in proto.items():
         if name in PAGED_LEAVES:
-            l, _, bs = leaf.shape[:3]
-            pools[name] = jnp.zeros((l, num_blocks, bs) + leaf.shape[3:], dtype)
+            l, _, bs, hk, dh = leaf.shape
+            pools[name] = jnp.zeros((l, num_blocks, bs, hk * dh), dtype)
         else:
             pools[name] = jnp.zeros(leaf.shape, leaf.dtype)
     return pools
 
 
 @jax.named_scope("kv_gather")
-def gather_cache_view(pools: dict, block_table) -> dict:
+def gather_cache_view(pools: dict, block_table, n_kv_heads: int) -> dict:
     """Materialize a contiguous per-slot cache view through block tables.
 
     block_table (B, VB) int32 — each slot's first VB blocks (0-padded).
-    Paged leaves (L, NB, bs, ...) -> (L, B, VB*bs, ...); dense leaves pass
-    through.  The result is shaped exactly like ``init_cache(cfg, B, VB*bs)``
-    so the model's prefill/decode/chunk entry points run on it unchanged.
+    Paged leaves (L, NB, bs, Hkv*Dh) -> (L, B, VB*bs, Hkv, Dh); dense leaves
+    pass through.  The result is shaped exactly like
+    ``init_cache(cfg, B, VB*bs)`` so the model's prefill/decode/chunk entry
+    points run on it unchanged.
     """
     view = {}
     for name, leaf in pools.items():
         if name in PAGED_LEAVES:
-            l, _, bs = leaf.shape[:3]
+            l, _, bs, lanes = leaf.shape
             b, vb = block_table.shape
-            g = leaf[:, block_table]                      # (L, B, VB, bs, ...)
-            view[name] = g.reshape((l, b, vb * bs) + leaf.shape[3:])
+            g = leaf[:, block_table]                      # (L, B, VB, bs, HD)
+            view[name] = g.reshape(
+                (l, b, vb * bs, n_kv_heads, lanes // n_kv_heads))
         else:
             view[name] = leaf
     return view
@@ -133,9 +141,9 @@ def scatter_cache_view(pools: dict, block_table, view: dict) -> dict:
     out = {}
     for name, leaf in pools.items():
         if name in PAGED_LEAVES:
-            l, _, bs = leaf.shape[:3]
+            l, _, bs, lanes = leaf.shape
             b, vb = block_table.shape
-            blk = view[name].reshape((l, b, vb, bs) + leaf.shape[3:])
+            blk = view[name].reshape((l, b, vb, bs, lanes))
             out[name] = leaf.at[:, block_table].set(blk)
         else:
             out[name] = view[name]

@@ -106,9 +106,8 @@ def _block(cfg, p, x, *, flag, pos, train, mode, cache=None, cache_len=None,
     h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
     if mode == "decode":
         attn_out, ac = layers.attention(
-            cfg, p["attn"], h, pos=pos, is_global=flag,
-            cache={"k": cache["k"], "v": cache["v"]}, cache_len=cache_len,
-            slot=slot, train=train,
+            cfg, p["attn"], h, pos=pos, is_global=flag, cache=cache,
+            cache_len=cache_len, slot=slot, train=train,
         )
         new_cache.update(ac)
     elif mode == "prefill":
@@ -282,28 +281,37 @@ def decode_step(cfg, params, tokens, cache: dict, t, train: bool = False):
     return _head(cfg, params, x), new_cache
 
 
-def flat_step(cfg, params, tokens, slot, pos, cache: dict, emit_row,
+def flat_step(cfg, params, tokens, slot, pos, pools: dict, table, emit_row,
               train: bool = False):
-    """Flat token-packed step for the paged serving engine (``flat`` policy).
+    """Flat token-packed step for the paged serving engine (``flat`` policy),
+    run against the block pools in place.
 
     tokens (T,) int32 — ONE ragged batch of real tokens from many slots
     packed along the sequence axis: several concurrent prefill chunks plus
     every decode token, budgeted purely in tokens (no per-slot padding
     rows);
     slot (T,) int32 — per-token cache slot; padding rows carry the sentinel
-    ``B`` (== cache batch size) and are fully masked / scattered to a
-    scratch row;
+    ``B`` (== table rows) and are fully masked, their K/V written to
+    scratch block 0;
     pos (T,) int32 — per-token absolute position (== its KV write offset);
+    pools — ``model_zoo.init_paged_cache`` pools, K/V (L, NB, bs, Hkv*Dh);
+    table (B, VB) int32 — each slot's first VB pool blocks (0-padded);
     emit_row (B,) int32 — for each slot, the flat row whose logits it
     samples (its last real token this step; engine masks non-emitting
     slots).
 
-    Returns (logits (B, V) gathered at ``emit_row``, updated caches).  The
+    Returns (logits (B, V) gathered at ``emit_row``, updated pools).  The
     head runs on B rows, not T — emit-row selection happens before the
     vocab matmul, so a wide prefill step never pays a (T, V) head.
 
+    The pools ride the layer scan as carry: each layer writes its T new K/V
+    rows into its pool rows in place, then reads its (B, VB*bs) view through
+    the table (``layers.attention``).  No view leaves a layer and nothing is
+    written back, so a step moves T rows plus one read per layer, not the
+    whole view.
+
     Like ``chunk_step``, a slot's rows may start at a nonzero position
-    against a pre-populated cache (prefix-cache fork); attention masks by
+    against pre-populated blocks (prefix-cache fork); attention masks by
     absolute position within the slot's segment.
     """
     assert cfg.family not in ("ssm", "hybrid"), \
@@ -311,18 +319,31 @@ def flat_step(cfg, params, tokens, slot, pos, cache: dict, emit_row,
     x = params["embed"][tokens][None, :, :] * math.sqrt(cfg.d_model)
     x = x.astype(jnp.float32)
     flags = global_flags(cfg)
+    # Each token's pool row, once for every layer: block table[slot, pos//bs]
+    # at offset pos % bs.  Padding rows go explicitly to scratch block 0:
+    # scatter modes clamp or drop out-of-range indices depending on the mode,
+    # so the destination is never left to them.
+    bs = pools["k"].shape[2]
+    real = slot < table.shape[0]
+    blk = jnp.where(real, table[jnp.where(real, slot, 0),
+                                jnp.where(real, pos // bs, 0)], 0)
+    off = jnp.where(real, pos % bs, 0)
 
     def body(carry, xs):
-        xv = carry
-        p, flag, cache_l = xs
+        xv, pk, pv = carry
+        p, flag, layer = xs
+        cache_l = {"k": pk, "v": pv, "layer": layer, "table": table,
+                   "blk": blk, "off": off}
         xv, _, nc = _block(cfg, p, xv, flag=flag, pos=pos, train=train,
                            mode="decode", cache=cache_l, slot=slot)
-        return xv, nc
+        return (xv, nc["k"], nc["v"]), None
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], flags, cache))
+    (x, pk, pv), _ = jax.lax.scan(
+        body, (x, pools["k"], pools["v"]),
+        (params["blocks"], flags, jnp.arange(cfg.n_layers)))
     sel = x[0, emit_row]                       # (B, D) emitting rows only
     logits = _head(cfg, params, sel[None])     # (1, B, V)
-    return logits[0], new_cache
+    return logits[0], {**pools, "k": pk, "v": pv}
 
 
 def chunk_step(cfg, params, tokens, pos, cache: dict, lengths, train: bool = False):

@@ -20,8 +20,8 @@ The module splits four ways:
   admitted prompts fork the cached leading blocks of an earlier request
   instead of recomputing them (``ServingEngine(prefix_cache=True)``);
 * this file           — the ``ServingEngine``/``Request`` API, the jitted
-  gather -> model -> scatter step, sampling, prefix registration, and
-  latency stats (per-request TTFT/TPOT).
+  steps (the flat step works on the block pools in place), sampling, prefix
+  registration, and latency stats (per-request TTFT/TPOT).
 
 Policies: ``flat`` (default for dense/MoE attention families) packs every
 step into one flat ``(T,)`` token vector — multiple concurrent prefill
@@ -296,11 +296,13 @@ def packed_fraction(params) -> float:
 
 
 # ---------------------------------------------------------------------------
-# Jitted step bodies (gather -> model -> scatter, fused in one XLA program)
+# Jitted step bodies.  The flat step reads and writes the block pools in
+# place; the chunked and whole-prompt steps run gather -> model -> scatter
+# over a contiguous view, fused in one XLA program.
 # ---------------------------------------------------------------------------
 
 def _chunk_call(cfg, params, pools, table, tokens, pos, lengths, emit_idx):
-    view = model_zoo.gather_cache_view(pools, table)
+    view = model_zoo.gather_cache_view(pools, table, cfg.n_kv_heads)
     logits, view = model_zoo.chunk_step(cfg, params, tokens, pos, view,
                                         lengths, train=False)
     pools = model_zoo.scatter_cache_view(pools, table, view)
@@ -309,15 +311,12 @@ def _chunk_call(cfg, params, pools, table, tokens, pos, lengths, emit_idx):
 
 
 def _flat_call(cfg, params, pools, table, tokens, slot, pos, emit_row):
-    view = model_zoo.gather_cache_view(pools, table)
-    sel, view = model_zoo.flat_step(cfg, params, tokens, slot, pos, view,
-                                    emit_row, train=False)
-    pools = model_zoo.scatter_cache_view(pools, table, view)
-    return sel, pools
+    return model_zoo.flat_step(cfg, params, tokens, slot, pos, pools, table,
+                               emit_row, train=False)
 
 
 def _whole_prefill_call(cfg, params, pools, table, batch, slot):
-    view = model_zoo.gather_cache_view(pools, table)
+    view = model_zoo.gather_cache_view(pools, table, cfg.n_kv_heads)
     slot_view = jax.tree.map(
         lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, 1), view)
     logits, slot_view = model_zoo.prefill(cfg, params, batch, slot_view,

@@ -2,24 +2,28 @@
 adapted to static-shape JAX).
 
 Device memory holds one *pool* of fixed-size token blocks per attention K/V
-leaf, (L, num_blocks, block_size, Hkv, Dh), instead of a dense
+leaf, lane-dense (L, num_blocks, block_size, Hkv*Dh), instead of a dense
 (slots, max_len) cache — so resident KV memory is proportional to live
 tokens, not to ``slots * max_len``.  A host-side free-list allocator hands
 blocks to slots; each slot's logical token positions map onto pool blocks
 through a per-slot block table.
 
-Before each model call the engine gathers the active slots' blocks into a
-contiguous (L, B, V, Hkv, Dh) view (V is a power-of-two bucket of block
-counts, so the jitted step re-traces only O(log max_len) times), runs the
-step, and scatters the view's blocks back.  Gather/scatter live in
-``model_zoo.gather_cache_view`` / ``scatter_cache_view`` and are fused into
-the engine's jitted step.
+Each model call gets the active slots' first V table columns (V is a
+power-of-two bucket of block counts, so the jitted step re-traces only
+O(log max_len) times).  The flat step (``transformer.flat_step``) carries the
+pools through its layer scan: each layer writes its new K/V rows into their
+pool rows in place and reads its (B, V*bs) view through the table.  The
+chunked and whole-prompt steps instead gather a contiguous
+(L, B, V*bs, Hkv, Dh) view, run the model on it and scatter it back
+(``model_zoo.gather_cache_view`` / ``scatter_cache_view``), fused into the
+engine's jitted step.
 
-Block 0 is reserved scratch: unallocated table entries point at it, so the
-static-shape gather/scatter of a short slot's padding reads/writes garbage
-that the causal mask guarantees is never attended.  O(1)-per-slot state (SSM
-conv tail + SSD state, enc-dec cross K/V) is not paged; it stays dense with a
-leading slot axis inside the same cache pytree.
+Block 0 is reserved scratch: unallocated table entries point at it, and the
+flat step writes its padding rows there, so the static-shape reads and
+writes of a short slot's padding touch garbage that the causal mask
+guarantees is never attended.  O(1)-per-slot state (SSM conv tail + SSD
+state, enc-dec cross K/V) is not paged; it stays dense with a leading slot
+axis inside the same cache pytree.
 
 Blocks are **ref-counted** so the prefix cache (``serving.prefix_cache``) can
 share one physical block between several slots and its own radix tree:

@@ -1,16 +1,22 @@
-"""Flat token-packed engine step (``policy="flat"``): token identity to the
-rectangular chunked and whole-prompt paths across dense/MoE and prefix-cache
-on/off, behavior under a preemption storm, planner budget/ordering
-properties, and the rejection accounting satellite."""
+"""Flat token-packed engine step (``policy="flat"``): one step in place on
+the block pools against the gather -> step-over-view -> scatter reference,
+the pool cells a step may touch, token identity to the rectangular chunked
+and whole-prompt paths across dense/MoE and prefix-cache on/off, behavior
+under a preemption storm, planner budget/ordering properties, and the
+rejection accounting satellite."""
 import dataclasses
+import math
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import repro.configs as configs
+from repro.models import layers, transformer
 from repro.models import model_zoo as zoo
-from repro.serving import Request, ServingEngine
+from repro.serving import PagedKVCache, Request, ServingEngine
+from repro.serving.engine import _flat_call
 from repro.serving.scheduler import ChunkedScheduler, FlatStepPlan, SlotState
 
 CHUNK = 8
@@ -38,6 +44,131 @@ def _mixed_reqs(maxnew=6, seed=7):
     return [Request(uid=i, prompt=rng.integers(0, 100, size=s).astype(np.int32),
                     max_new_tokens=maxnew)
             for i, s in enumerate(lens)]
+
+
+# ---------------------------------------------------------------------------
+# One step in place on the pools
+# ---------------------------------------------------------------------------
+
+def _view_step(cfg, params, tokens, slot, pos, view, emit_row):
+    """The flat step as it ran over a gathered (L, B, Vtok, Hkv, Dh) view:
+    each layer writes its rows into the view (padding rows to a dump row
+    past the live cells), attends over the whole view flattened slot-major
+    under the segment mask, and the scan stacks the layer views back.
+    Dense family without qk-norm, windows or softcaps."""
+    assert not (cfg.qk_norm or cfg.window_pattern or cfg.attn_softcap)
+    h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    b, vtok = view["k"].shape[1:3]
+    n = tokens.shape[0]
+    widx = jnp.where(slot < b, slot * vtok + pos, b * vtok)
+    kidx = jnp.arange(b * vtok)
+    valid = (kidx[None] // vtok == slot[:, None]) \
+        & (kidx[None] % vtok <= pos[:, None])
+
+    def write(c, u):
+        flat = c.reshape((b * vtok, hk, dh))
+        flat = jnp.concatenate([flat, jnp.zeros_like(flat[:1])])
+        return flat.at[widx].set(u)[:b * vtok].reshape(c.shape)
+
+    def body(x, xs):
+        p, ck, cv = xs
+        a = p["attn"]
+        hx = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        q = layers.linear(a["wq"], hx, False).reshape(1, n, h, dh)
+        k = layers.linear(a["wk"], hx, False).reshape(1, n, hk, dh)
+        v = layers.linear(a["wv"], hx, False).reshape(1, n, hk, dh)
+        q = layers.rope(q, pos, cfg.rope_theta)
+        k = layers.rope(k, pos, cfg.rope_theta)
+        ck, cv = write(ck, k[0]), write(cv, v[0])
+        qg = q.reshape(1, n, hk, h // hk, dh)
+        scores = jnp.einsum("bshgd,bthd->bhgst", qg,
+                            ck.reshape(1, b * vtok, hk, dh)) / jnp.sqrt(
+            jnp.float32(dh))
+        scores = jnp.where(valid, scores, jnp.finfo(scores.dtype).min)
+        probs = jax.nn.softmax(scores, axis=-1)
+        ctx = jnp.einsum("bhgst,bthd->bshgd", probs,
+                         cv.reshape(1, b * vtok, hk, dh))
+        x = x + layers.linear(a["wo"], ctx.reshape(1, n, h * dh), False)
+        x = x + layers.mlp(p["mlp"], layers.rmsnorm(p["ln2"], x,
+                                                    cfg.norm_eps), False)
+        return x, (ck, cv)
+
+    x = params["embed"][tokens][None] * math.sqrt(cfg.d_model)
+    x, (ks, vs) = jax.lax.scan(body, x.astype(jnp.float32),
+                               (params["blocks"], view["k"], view["v"]))
+    logits = transformer._head(cfg, params, x[0, emit_row][None])[0]
+    return logits, {"k": ks, "v": vs}
+
+
+@pytest.fixture(scope="module")
+def one_step(dense_model):
+    """Three slots over garbage-filled pools (block size 8, 4-block view):
+    slot 0 decodes at position 21; slot 1 forks slot 0's first two blocks
+    as a cached prefix and runs a mid-prompt chunk of 8 rows at 16..23;
+    slot 2 has a one-block table and decodes at 5; 3 padding rows.  Both
+    the in-place step and the view reference run from the same pools."""
+    cfg, params = dense_model
+    kv = PagedKVCache(cfg, slots=3, max_len=32, block_size=8, num_blocks=16)
+    assert kv.ensure(0, 22)
+    kv.fork_blocks(1, [int(b) for b in kv.table[0, :2]])
+    assert kv.ensure(1, 24)
+    assert kv.ensure(2, 6)
+    key_k, key_v = jax.random.split(jax.random.PRNGKey(5))
+    pools = {"k": jax.random.normal(key_k, kv.pools["k"].shape),
+             "v": jax.random.normal(key_v, kv.pools["v"].shape)}
+    table = kv.table_view(4)
+    slot = np.array([0] + [1] * 8 + [2] + [3] * 3, np.int32)
+    pos = np.array([21] + list(range(16, 24)) + [5] + [0] * 3, np.int32)
+    tokens = np.random.default_rng(2).integers(
+        0, cfg.vocab_size, size=slot.size).astype(np.int32)
+    emit_row = np.array([0, 8, 9], np.int32)
+    args = (table, jnp.asarray(tokens), jnp.asarray(slot), jnp.asarray(pos),
+            jnp.asarray(emit_row))
+    got = jax.jit(lambda p, pl, *a: _flat_call(cfg, p, pl, *a))(
+        params, pools, *args)
+
+    def reference(p, pl, tbl, tk, sl, ps, er):
+        view = zoo.gather_cache_view(pl, tbl, cfg.n_kv_heads)
+        logits, view = _view_step(cfg, p, tk, sl, ps, view, er)
+        return logits, zoo.scatter_cache_view(pl, tbl, view)
+
+    want = jax.jit(reference)(params, pools, *args)
+    written = {(int(table[s, p // 8]), int(p % 8))
+               for s, p in zip(slot, pos) if s < 3}
+    return {"pools": pools, "got": got, "want": want, "written": written,
+            "shared": [int(b) for b in kv.table[0, :2]]}
+
+
+def test_in_place_step_matches_view_reference(one_step):
+    """Logits equal to float32 rounding; every pool cell outside scratch
+    block 0 equal to the reference's."""
+    (logits, pools), (ref_logits, ref_pools) = one_step["got"], \
+        one_step["want"]
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
+                               rtol=1e-5, atol=1e-5)
+    for name in zoo.PAGED_LEAVES:
+        assert pools[name].shape == ref_pools[name].shape
+        np.testing.assert_allclose(np.asarray(pools[name])[:, 1:],
+                                   np.asarray(ref_pools[name])[:, 1:],
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_step_writes_only_its_rows(one_step):
+    """The only pool rows a step changes are its tokens' rows
+    ``(table[slot, pos // bs], pos % bs)``, in every layer, and scratch
+    block 0; the blocks slot 1 forked from slot 0 stay bitwise as they
+    were."""
+    before = one_step["pools"]
+    _, after = one_step["got"]
+    for name in zoo.PAGED_LEAVES:
+        old, new = np.asarray(before[name]), np.asarray(after[name])
+        changed = (old != new).any(axis=-1)                 # (L, NB, bs)
+        rows = {(int(b), int(o)) for _, b, o in np.argwhere(changed)}
+        assert rows - {r for r in rows if r[0] == 0} == one_step["written"]
+        for b, o in one_step["written"]:
+            assert changed[:, b, o].all()
+        for b in one_step["shared"]:
+            np.testing.assert_array_equal(new[:, b], old[:, b])
 
 
 # ---------------------------------------------------------------------------

@@ -93,17 +93,22 @@ class TestGatherScatter:
         key = jax.random.PRNGKey(0)
         kv.pools["k"] = jax.random.normal(key, kv.pools["k"].shape)
         table = kv.table_view(2)
-        view = zoo.gather_cache_view(kv.pools, table)
+        hk, dh = cfg.n_kv_heads, cfg.head_dim
+        # A pool block is lane-dense (bs, Hkv*Dh); the view's rows are
+        # (Hkv, Dh).
+        block = lambda pool, b: np.asarray(pool)[:, b].reshape(  # noqa: E731
+            -1, kv.block_size, hk, dh)
+        view = zoo.gather_cache_view(kv.pools, table, hk)
         s0, s1 = int(table[0, 0]), int(table[1, 1])
         np.testing.assert_array_equal(
-            np.asarray(view["k"])[:, 0, :4], np.asarray(kv.pools["k"])[:, s0])
+            np.asarray(view["k"])[:, 0, :4], block(kv.pools["k"], s0))
         np.testing.assert_array_equal(
-            np.asarray(view["k"])[:, 1, 4:8], np.asarray(kv.pools["k"])[:, s1])
+            np.asarray(view["k"])[:, 1, 4:8], block(kv.pools["k"], s1))
         # scatter writes modified blocks back to their pool homes
         view["k"] = view["k"] + 1.0
         pools2 = zoo.scatter_cache_view(kv.pools, table, view)
         np.testing.assert_array_equal(
-            np.asarray(pools2["k"])[:, s0], np.asarray(view["k"])[:, 0, :4])
+            block(pools2["k"], s0), np.asarray(view["k"])[:, 0, :4])
         # untouched pool blocks stay untouched
         owned = set(np.asarray(table).ravel().tolist())
         for blk in range(kv.num_blocks):

@@ -2,10 +2,11 @@
 
 The TPU compiler ships with libtpu, so these tests describe a ``v5e:2x2``
 topology and compile for one of its chips: the four Pallas kernels at
-bitnet-2b-4t's 2560 x 6912 BitLinear shape, and one full-width flat serving
-step.  Mosaic refuses here what it would refuse on the chip (shape casts it
+bitnet-2b-4t's 2560 x 6912 BitLinear shape, and full-width flat serving
+steps.  Mosaic refuses here what it would refuse on the chip (shape casts it
 cannot lay out, more VMEM than a kernel may use), and the compiler reports
-the step's device memory.  Nothing runs, so nothing here is a time.
+the step's device memory and the copies it makes.  Nothing runs, so nothing
+here is a time.
 
 The topology is described only inside the ``one_chip`` fixture: only one
 process at a time may load the TPU library, and only the test worker that
@@ -185,6 +186,51 @@ def test_full_width_flat_step_fits_one_chip(one_chip):
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes > 3e9      # the real widths, not a cut
     assert used < V5E.hbm_bytes, used
+
+
+@pytest.fixture(scope="module")
+def chat_step(one_chip):
+    """bitnet-2b-4t at published widths in the chat cell's shape: 10 slots,
+    block size 16, 1131 pool blocks (10 slots x 113 blocks of max context
+    1808, + scratch), view bucket 128.  Returns a compile of the flat step
+    with the pools donated, as the engine runs it, at a given width."""
+    cfg = configs.get("bitnet-2b-4t")
+    slots, view_blocks, num_blocks, block = 10, 128, 1131, 16
+    params = jax.eval_shape(
+        lambda k: freeze_params(zoo.init_params(cfg, k), sparse=False),
+        jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: zoo.init_paged_cache(
+        cfg, slots, num_blocks, block))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+
+    def compile_at(t):
+        return jax.jit(
+            lambda p, pools, tbl, tk, sl, ps, er:
+            _flat_call(cfg, p, pools, tbl, tk, sl, ps, er),
+            donate_argnums=(1,)).lower(
+            _specs(params, one_chip), _specs(pools, one_chip),
+            i32(slots, view_blocks), i32(t), i32(t), i32(t),
+            i32(slots)).compile()
+    return pools["k"], compile_at
+
+
+@pytest.mark.parametrize("width,temp_limit", [(266, 1e9), (10, 1e8)],
+                         ids=["chunk", "decode"])
+def test_flat_step_updates_pool_in_place(chat_step, width, temp_limit):
+    """The flat step writes and reads the block pools in place: the
+    compiled program copies no pool leaf into or out of its layer loop,
+    and its temporaries stay under one pool leaf."""
+    import re
+
+    leaf, compile_at = chat_step
+    compiled = compile_at(width)
+    shape = "f32[" + ",".join(map(str, leaf.shape)) + "]"
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r" copy(-start)?\(", line) and shape in line]
+    assert copies == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < leaf.size * leaf.dtype.itemsize, temp
+    assert temp < temp_limit, temp
 
 
 def test_packed_init_fits_one_chip(one_chip):
