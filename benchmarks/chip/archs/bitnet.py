@@ -1,13 +1,18 @@
-"""Plain reference of the served dense decoder, in float32 (or in a lower
-precision, for the control).  It imports nothing of the program.
+"""The dense decoder this repository serves: BitNet b1.58 and, through
+``llama.py``, llama-type models such as deepseek-coder.
 
-It draws the weights itself from the run's seed, by the rule the system
-under test draws them (below), and computes the layer equations of this
-repository's dense model: pre-norm blocks, RoPE, grouped-query causal
-attention, a gated-SiLU MLP, every projection but the embedding and the head
-a BitLinear (absmean-ternarised weights, per-token absmax int8
-activations).  Where these depart from a published model, the
-configuration file's ``departures`` say so.
+An architecture file holds everything the harness needs to know of one
+``model_type`` (``spec.arch``): the program's ``ModelConfig`` for a
+configuration file, the plain reference and its weight rule, and the
+operations and bytes of a serving step, whole and by part.
+
+The plain reference computes, in float32 (or in a lower precision, for the
+control), the layer equations of this repository's dense model: pre-norm
+blocks, RoPE, grouped-query causal attention, a gated-SiLU MLP, every
+projection but the embedding and the head a BitLinear (absmean-ternarised
+weights, per-token absmax int8 activations).  It imports nothing of the
+program.  Where these depart from a published model, the configuration
+file's ``departures`` say so.
 
 Weight rule, from ``PRNGKey(seed & 0xffffffff)`` folded with ``seed >> 32``:
 split into (layers, embed, head, -); layer i's key is the i-th of
@@ -29,6 +34,18 @@ bfloat16; ``"float8"`` keeps float32 arrays but rounds the inputs of every
 float matrix product (attention and the head) to float8 (e4m3), as the
 step below the TPU's default one-pass bfloat16 products.  BitLinear
 products stay exact in both (int8 activations times ternary weights).
+
+Counts (``counts.py`` gives the conventions), per step:
+
+* ``bitlinear``: every BitLinear's 2 K M operations per real token; its
+  2-bit planes and 2-byte channel scales;
+* ``attention``: for a token at position p, 2 H Dh (p + 1) for QK^T and as
+  much for PV, in each layer; each slot's keys and values read and the new
+  ones written, at 2 bytes;
+* ``head``: 2 D V operations per emitted row; the head's weight at 2 bytes.
+
+The parts' operations sum to ``step_counts``' exactly; the step's bytes
+add the norms and the embedding rows.
 """
 from __future__ import annotations
 
@@ -39,7 +56,41 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import counts
+
+# The device scopes the step is read under: each part that ``step_parts``
+# counts, with the peak its operations are held to ...
+PARTS = {"bitlinear": "int8_ops", "attention": "bf16_flops",
+         "head": "bf16_flops"}
+# ... and the KV pool's per-layer read and write, timed but not counted
+# apart (their bytes are attention's).
+POOL = ("kv_gather", "kv_scatter")
 Q_BLOCK = 512
+
+# Published config.json keys -> the program's ModelConfig fields.
+_ARCH_KEYS = {
+    "num_hidden_layers": "n_layers",
+    "hidden_size": "d_model",
+    "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads",
+    "intermediate_size": "d_ff",
+    "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "norm_eps",
+    "tie_word_embeddings": "tie_embeddings",
+}
+
+
+def model_config(config: dict):
+    """The program's ``ModelConfig`` for a configuration file."""
+    from repro.configs.base import ModelConfig
+
+    if config["hidden_act"] != "silu":
+        raise ValueError(f"{config['name']}: the program's dense MLP is "
+                         f"gated SiLU, not {config['hidden_act']!r}")
+    kw = {dst: config[src] for src, dst in _ARCH_KEYS.items()}
+    return ModelConfig(name=config["name"], family="dense", mlp_gated=True,
+                       ternary=True, **kw)
 
 
 def weight_key(seed: int):
@@ -208,3 +259,38 @@ def logits_at(config: dict, seed: int, seqs: list[np.ndarray],
         sel = jnp.concatenate([x[i, jnp.asarray(r)] for i, r in
                                enumerate(rows)], axis=0)
         return _head(items, ke, kh, sel, dtype, low)
+
+
+# -- operations and bytes ---------------------------------------------------
+
+def step_parts(config: dict, slots, emit: int) -> dict:
+    """{part: (operations, bytes)} of one step; ``slots`` and ``emit`` as
+    ``counts.py`` describes them."""
+    s = _shapes(config)
+    d, h, kv, dh, ff = s["d"], s["h"], s["kv"], s["dh"], s["ff"]
+    linears = [(d, h * dh), (d, kv * dh), (d, kv * dh), (h * dh, d),
+               (d, ff), (d, ff), (ff, d)]
+    tokens = sum(n for n, _ in slots)
+    weights = s["layers"] * sum(k * m for k, m in linears)
+    planes = s["layers"] * sum(k * m / 4 + counts.BF16 * m
+                               for k, m in linears)
+    context = sum(counts.context_sum(n, e) for n, e in slots)
+    kv_token = s["layers"] * 2 * kv * dh * counts.BF16
+    return {
+        "bitlinear": (float(2 * tokens * weights), float(planes)),
+        "attention": (float(4 * s["layers"] * h * dh * context),
+                      float(kv_token * sum(e for _, e in slots))),
+        "head": (float(2 * emit * d * s["vocab"]),
+                 float(d * s["vocab"] * counts.BF16)),
+    }
+
+
+def step_counts(config: dict, slots, emit: int) -> tuple[float, float]:
+    """(operations, bytes) of one step: its parts, and the bytes of the
+    block norms, the final norm and the embedding rows."""
+    s = _shapes(config)
+    parts = step_parts(config, slots, emit).values()
+    norms = (2 * s["layers"] + 1) * s["d"] * counts.BF16
+    embed = sum(n for n, _ in slots) * s["d"] * counts.BF16
+    return (sum(o for o, _ in parts),
+            sum(b for _, b in parts) + norms + embed)

@@ -43,16 +43,16 @@ def sweep(cell, seed: int, seconds: float, rates: list[float]) -> None:
     import driver as driver_mod
     import harness
     import readers
-    import reference
     import spec
     import stats
     import traffic
     from repro.serving import ServingEngine, init_packed_params
 
     harness._devices(cell.chips, True)
-    cfg = spec.model_config(cell.config)
+    arch = spec.arch(cell.config)
+    cfg = arch.model_config(cell.config)
     eng = cell.settings["engine"]
-    params = init_packed_params(cfg, reference.weight_key(seed))
+    params = init_packed_params(cfg, arch.weight_key(seed))
     jax.block_until_ready(params)
     engine = ServingEngine(
         cfg, params, packed=True, batch_slots=eng["slots"],

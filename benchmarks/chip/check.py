@@ -4,13 +4,14 @@ While the window runs, the driver keeps on the device the program's own
 logit of every token it serves (``driver.Driver.served_logits``).  Once the
 window has closed and the program's state is freed, a sample of the
 requests the run finished, drawn from the seed and always holding the
-longest, goes through the plain reference (``reference.py``): each prompt
-with its served tokens, teacher-forced.  At each served position the
-token's error is the distance from the reference's best logit to the
-program's logit of the token it served, by way of the reference's logit of
-that token: (best - reference[token]) + |reference[token] - program[token]|.
-Where the served token is the reference's choice, as greedy decoding makes
-it in a sound run, that is |best - program[token]|, rounding alone.  A token
+longest, goes through the plain reference (the ``logits_at`` of the
+configuration's architecture file, ``spec.arch``): each prompt with its
+served tokens, teacher-forced.  At each served position the token's error
+is the distance from the reference's best logit to the program's logit of
+the token it served, by way of the reference's logit of that token:
+(best - reference[token]) + |reference[token] - program[token]|.  Where
+the served token is the reference's choice, as greedy decoding makes it in
+a sound run, that is |best - program[token]|, rounding alone.  A token
 the reference would not choose adds the reference's own margin, even where
 the program's logit of it is as high as a right token's would be: a slot
 handed another slot's logits serves a token that is the best of the wrong
@@ -37,7 +38,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-import reference
+import spec
 
 
 def sample(finished: list, seed: int, n: int) -> list:
@@ -71,7 +72,8 @@ def errors(config: dict, seed: int, reqs: list, served: dict, pad_to: int,
     reads NaN).  With ``low``, the control serves its own best token and
     its logit of it takes the program's place."""
     seqs, rows = _teacher_forced(reqs)
-    ref = reference.logits_at(config, seed, seqs, rows, pad_to=pad_to)
+    logits_at = spec.arch(config).logits_at
+    ref = logits_at(config, seed, seqs, rows, pad_to=pad_to)
     if low is None:
         toks = np.concatenate([np.asarray(r.out_tokens, np.int32)
                                for r in reqs])
@@ -79,8 +81,7 @@ def errors(config: dict, seed: int, reqs: list, served: dict, pad_to: int,
                         for r in reqs for t in range(len(r.out_tokens))],
                        np.float64)
     else:
-        ctrl = reference.logits_at(config, seed, seqs, rows, pad_to=pad_to,
-                                   low=low)
+        ctrl = logits_at(config, seed, seqs, rows, pad_to=pad_to, low=low)
         toks = np.asarray(jnp.argmax(ctrl, axis=-1), np.int32)
         got = np.asarray(jnp.max(ctrl, axis=-1), np.float64)
     best = np.asarray(jnp.max(ref, axis=-1), np.float64)

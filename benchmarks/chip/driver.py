@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import counts
+import spec
 
 # How long after the window closes the driver waits for the first token of
 # the last requests due in it, before it counts them as failed.
@@ -220,9 +220,9 @@ class Driver:
                 "prompt_len": int(len(r.prompt)), "max_new": r.max_new_tokens,
                 "done": bool(r.done)})
         steps = []
+        step_counts = spec.arch(self.config).step_counts
         for s, kv in zip(self.steps, self.kv_in_use):
-            ops, nbytes = counts.step_counts(self.config, s["slots"],
-                                             s["emit"])
+            ops, nbytes = step_counts(self.config, s["slots"], s["emit"])
             steps.append({**s, "t0": s["t0"] - w0, "t1": s["t1"] - w0,
                           "kv_blocks": kv, "ops": ops, "bytes": nbytes})
         return {"window_s": seconds, "loop": self.loop, "requests": reqs,

@@ -6,6 +6,7 @@ skipped and, for the fault tests, the timed path broken underneath.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import sys
 import time
@@ -14,7 +15,8 @@ import numpy as np
 
 import check
 import driver as driver_mod
-import reference
+import peaks
+import scopes
 import spec
 import traffic
 import xtrace
@@ -32,6 +34,11 @@ def _devices(chips: int, require_chip: bool):
         raise NoChip(f"this cell needs {chips} TPU chip(s); JAX found "
                      f"{len(devs)} {devs[0].platform!r} device(s)")
     return devs[:chips]
+
+
+def _peaks(dev) -> dict | None:
+    """The chip's published peaks; None off a TPU."""
+    return peaks.peaks(dev.device_kind) if dev.platform == "tpu" else None
 
 
 def _compile_log():
@@ -102,14 +109,13 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
 
     devs = _devices(cell.chips, require_chip)
     dev = devs[0]
-    import peaks as peaks_mod
-    chip_peaks = peaks_mod.peaks(dev.device_kind) if dev.platform == "tpu" \
-        else None
+    chip_peaks = _peaks(dev)
     compiles = _compile_log()
 
-    cfg = spec.model_config(cell.config)
+    arch = spec.arch(cell.config)
+    cfg = arch.model_config(cell.config)
     eng = cell.settings["engine"]
-    params = init_packed_params(cfg, reference.weight_key(seed))
+    params = init_packed_params(cfg, arch.weight_key(seed))
     jax.block_until_ready(params)
     engine = ServingEngine(
         cfg, params, packed=True, batch_slots=eng["slots"],
@@ -127,7 +133,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
                             loop=cell.traffic["loop"],
                             clients=int(cell.settings.get("clients", 0)))
     opened = {}
-    tracer = xtrace.WindowTracer(cell.chips) if trace else None
+    tracer = xtrace.WindowTracer(cell.chips, functools.partial(
+        scopes.reduce_xspace, names=scopes.step_scopes(arch))) \
+        if trace else None
     record = drv.run(float(cell.settings["preroll_s"]), seconds,
                      on_open=lambda: opened.setdefault(
                          "t", time.perf_counter()),
@@ -178,11 +186,20 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     result = {"correct": bool(correct), "attempted": len(window_reqs),
               "failed": sum(1 for r in window_reqs if not r["stamps"]),
               "metrics": metrics, "device": device}
-    if trace and record.get("trace"):
-        device["busy_s"] = record["trace"]["busy_s"]
-        device["window_s"] = record["trace"]["window_s"]
-        result["breakdown"] = {"device_ops": record["trace"]["device_ops"],
-                               "idle_gaps": record["trace"]["idle_gaps"]}
+    tr = record.get("trace") if trace else None
+    if tr:
+        device["busy_s"] = tr["busy_s"]
+        device["window_s"] = tr["window_s"]
+        result["breakdown"] = {
+            "device_ops": tr["device_ops"],
+            "idle_gaps": tr["idle_gaps"][:10],
+            "device_scopes": xtrace.top(dict(tr["scope_s"])),
+            "host_span_s": xtrace.top(tr["host_span_s"])}
+        if tr["busy_s"] and not any(v for k, v in tr["scope_s"]
+                                    if k != scopes.OTHER):
+            print("no device op fell under a step scope: were the programs "
+                  "loaded from a compile cache filled by code without the "
+                  "scopes?", file=sys.stderr)
     result["checks"] = compared
     for name, c in compared.items():
         print(f"check {name}: {c['value']} (limit {c['limit']})",

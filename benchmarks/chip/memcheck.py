@@ -44,7 +44,7 @@ def check(workload: str, one_chip) -> dict:
     from repro.serving.engine import _flat_call, freeze_params
 
     cell = spec.resolve(workload)
-    cfg = spec.model_config(cell.config)
+    cfg = spec.arch(cell.config).model_config(cell.config)
     eng = cell.settings["engine"]
     slots, bs = eng["slots"], eng["block_size"]
     max_blocks = math.ceil(eng["max_len"] / bs)

@@ -5,4 +5,4 @@ import scopes
 
 
 def read(rec):
-    return scopes.part_roofline(rec, "attention", "bf16_flops")
+    return scopes.part_roofline(rec, "attention")

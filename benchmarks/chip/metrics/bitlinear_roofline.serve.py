@@ -5,4 +5,4 @@ import scopes
 
 
 def read(rec):
-    return scopes.part_roofline(rec, "bitlinear", "int8_ops")
+    return scopes.part_roofline(rec, "bitlinear")

@@ -1,7 +1,8 @@
-"""Device self time under the kv_gather and kv_scatter scopes per traced
-step (pool to view and back), in ms."""
+"""Device self time under the KV pool's scopes (kv_gather and kv_scatter:
+each layer's read through the block table and its row write) per traced
+step, in ms."""
 import scopes
 
 
 def read(rec):
-    return scopes.kv_copy_ms(rec)
+    return scopes.pool_ms(rec)

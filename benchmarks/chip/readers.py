@@ -9,6 +9,10 @@ from __future__ import annotations
 
 import stats
 
+# The engine's host phases, without the wait on the device.
+ENGINE_HOST = ("engine.admit", "engine.plan", "engine.dispatch",
+               "engine.sample", "engine.emit")
+
 
 def in_window(rec: dict, t) -> bool:
     return t is not None and 0 <= t < rec["window_s"]
@@ -87,6 +91,17 @@ def device_idle(rec: dict) -> float | None:
     if not tr or not tr.get("window_s"):
         return None
     return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
+
+
+def engine_host_ms(rec: dict) -> float | None:
+    """The engine's host phases (``ENGINE_HOST``: all but the wait) per
+    traced step, in ms."""
+    tr = rec.get("trace") or {}
+    spans = tr.get("host_span_s") or {}
+    steps = traced_steps(rec)
+    if not steps or not any(n in spans for n in ENGINE_HOST):
+        return None
+    return 1e3 * sum(spans.get(n, 0.0) for n in ENGINE_HOST) / len(steps)
 
 
 def percentile_ms(values, p: float) -> float | None:

@@ -8,12 +8,29 @@ them sits in a file of its own, found by its name:
 * ``traffic/<traffic>.json``  the traffic mix: loop kind and length laws;
 * ``cells/<workload>.json``   what this cell fixes for its pair: engine
   settings, the offered rate or client count, the correctness limit;
-* ``metrics/<metric>.py``     one reader per per-layer metric.
+* ``metrics/<metric>.py``     one reader per per-layer metric;
+* ``archs/<model_type>.py``   one file per architecture, found by the
+  configuration file's ``model_type``.
 
 A later cell adds files and ``BENCHMARK.json`` entries; nothing here changes.
+
+To add an architecture, write ``archs/<model_type>.py`` with
+``model_config(config)`` (the program's ``ModelConfig``), ``weight_key(seed)``
+and ``logits_at(config, seed, seqs, rows, pad_to, low)`` (the plain
+reference, which imports nothing of the program, its weight rule in its
+docstring), ``step_counts(config, slots, emit)`` (operations and bytes of
+one step, as ``counts.py`` counts them), ``PARTS`` (device scope -> the
+peak in ``peaks.py`` its operations are held to), ``step_parts(config,
+slots, emit)`` (``{scope: (operations, bytes)}`` for each of ``PARTS``,
+their operations summing to the step's) and ``POOL`` (the scopes the KV
+pool is read and written under).  A model type the program serves on the
+path of another is a file that names it: ``SAME_AS = "<model_type>"``.
+The trace is then split by those scopes, and a per-layer reader of a part
+is one more file in ``metrics/``.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from dataclasses import dataclass, field
@@ -21,6 +38,7 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+ARCHS = HERE / "archs"
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -66,37 +84,30 @@ def resolve(workload: str, bench: dict | None = None) -> Cell:
         per_layer=[m for m in bench["per_layer"] if _reports(m, workload)])
 
 
-def metric_reader(name: str):
-    """``metrics/<name>.py``'s ``read(record)``."""
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-# Published config.json keys -> the program's ModelConfig fields.
-_ARCH_KEYS = {
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-}
+def metric_reader(name: str):
+    """``metrics/<name>.py``'s ``read(record)``."""
+    return _load(HERE / "metrics" / f"{name}.py",
+                 f"bench_metric_{name.replace('.', '_')}").read
 
 
-def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
+@functools.cache
+def _arch_file(path: Path):
+    if not path.is_file():
+        raise FileNotFoundError(f"no architecture file {path} for "
+                                f"model_type {path.stem!r}")
+    mod = _load(path, f"bench_arch_{path.stem}")
+    same = getattr(mod, "SAME_AS", None)
+    return _arch_file(path.with_name(f"{same}.py")) if same else mod
 
-    if config["hidden_act"] != "silu":
-        raise ValueError(f"{config['name']}: the program's dense MLP is "
-                         f"gated SiLU, not {config['hidden_act']!r}")
-    kw = {dst: config[src] for src, dst in _ARCH_KEYS.items()}
-    return ModelConfig(name=config["name"], family="dense", mlp_gated=True,
-                       ternary=True, **kw)
+
+def arch(config: dict):
+    """The architecture file of ``config["model_type"]``:
+    ``archs/<model_type>.py``, or the file its ``SAME_AS`` names."""
+    return _arch_file(ARCHS / f"{config['model_type']}.py")

@@ -1,19 +1,21 @@
 """The benchmark's arithmetic on the CPU, with no timing claims: operation
 and byte counts against hand counts, latency and rate arithmetic on
-synthetic stamps, and every cell resolving its files by name."""
+synthetic stamps, and every cell resolving its files by name, its
+architecture file by ``model_type``."""
 import json
 import statistics
 
 import numpy as np
 import pytest
 
-import counts
 import readers
 import spec
 import stats
 import traffic
 
 BITNET = json.loads((spec.HERE / "configs" / "bitnet2b.json").read_text())
+CONFIGS = {p.stem: json.loads(p.read_text())
+           for p in sorted((spec.HERE / "configs").glob("*.json"))}
 
 # bitnet-2b-4t per layer: q, o 2560x2560; k, v 2560x640; gate, up 2560x6912;
 # down 6912x2560: 6,553,600*2 + 1,638,400*2 + 17,694,720*3 = 69,468,160
@@ -38,14 +40,18 @@ HAND = {
 @pytest.mark.parametrize("case", sorted(HAND))
 def test_counts_match_hand_counts(case):
     slots, emit, ops, nbytes = HAND[case]
-    assert counts.step_counts(BITNET, slots, emit) == (ops, nbytes)
+    assert spec.arch(BITNET).step_counts(BITNET, slots, emit) == (ops, nbytes)
 
 
-def test_counts_add_over_slots():
-    a = counts.step_counts(BITNET, [(1, 500)], 1)
-    b = counts.step_counts(BITNET, [(3, 40)], 0)
-    both = counts.step_counts(BITNET, [(1, 500), (3, 40)], 1)
-    fixed = counts.step_counts(BITNET, [], 0)   # planes, norms, head bytes
+@pytest.mark.parametrize("name", sorted(CONFIGS))
+def test_counts_add_over_slots(name):
+    config = CONFIGS[name]
+    count = lambda slots, emit: spec.arch(config).step_counts(  # noqa: E731
+        config, slots, emit)
+    a = count([(1, 500)], 1)
+    b = count([(3, 40)], 0)
+    both = count([(1, 500), (3, 40)], 1)
+    fixed = count([], 0)   # planes, norms, head bytes
     assert both[0] == pytest.approx(a[0] + b[0])
     assert both[1] == pytest.approx(a[1] + b[1] - fixed[1])
 
@@ -127,7 +133,13 @@ def test_cell_resolves_its_files_by_name(workload):
         assert callable(spec.metric_reader(m["name"]))
     entry = next(c for c in BENCH["configs"] if c["name"] == cell.config["name"])
     assert entry["reduced"] == cell.config["reduced"]
-    assert spec.model_config(cell.config).d_model == cell.config["hidden_size"]
+    model = spec.arch(cell.config).model_config(cell.config)
+    assert model.d_model == cell.config["hidden_size"]
+
+
+def test_unknown_model_type_names_the_file():
+    with pytest.raises(FileNotFoundError, match=r"archs/mamba9\.py"):
+        spec.arch(dict(BITNET, model_type="mamba9"))
 
 
 @pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])

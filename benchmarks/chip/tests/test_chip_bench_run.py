@@ -13,7 +13,6 @@ import calibrate
 import check
 import faults
 import harness
-import reference
 import spec
 
 TINY = {
@@ -133,12 +132,14 @@ def test_error_counts_a_token_the_reference_would_not_choose():
     greedy: list = []
     for _ in range(3):
         seq = np.concatenate([prompt, np.asarray(greedy, np.int32)])
-        logits = reference.logits_at(TINY, 1, [seq], [np.array([len(seq) - 1])])
+        logits = spec.arch(TINY).logits_at(TINY, 1, [seq],
+                                           [np.array([len(seq) - 1])])
         greedy.append(int(np.argmax(np.asarray(logits)[0])))
 
     def best_and_at(req):
         seqs, rows = check._teacher_forced([req])
-        ref = np.asarray(reference.logits_at(TINY, 1, seqs, rows, pad_to=64))
+        ref = np.asarray(spec.arch(TINY).logits_at(TINY, 1, seqs, rows,
+                                                  pad_to=64))
         return ref.max(-1), ref[np.arange(len(req.out_tokens)), req.out_tokens]
 
     sound = SimpleNamespace(uid=0, prompt=prompt, out_tokens=greedy)

@@ -53,7 +53,7 @@ def test_text_proto_round_trip():
     ((91.5,), 90.5),                  # up to 10 s, never the first half
 ])
 def test_trace_slice_holds_arrivals_with_room(arrivals, start):
-    tr = xtrace.WindowTracer()
+    tr = xtrace.WindowTracer(1, None)
     tr.plan(50.0, 100.0, arrivals)
     assert (tr.start_at, tr.stop_at) == (pytest.approx(start), 100.0)
     tr.plan(0.0, 1.5, (0.9,))         # a short window: its second half

@@ -8,7 +8,8 @@ for its prefill, so that it holds steps with prompt chunks beside pure
 decode steps; every seed has the same schedule, so the same slice.  The trace
 (``.xplane.pb``) is read with ``jax.profiler.ProfileData`` into a small
 table: the device's operations and program executions on each chip used,
-and the benchmark's own host spans.  ``reduce_table`` turns the table into
+and the host spans of the driver and the engine.  ``reduce_table`` turns
+the table into
 
 * ``busy_s``: the union of the intervals in which an operation ran on the
   device, averaged over the chips used;
@@ -18,10 +19,16 @@ and the benchmark's own host spans.  ``reduce_table`` turns the table into
   many a step runs (the step, the sampler, the driver's logit capture);
 * ``device_ops``: the ten operations that took the most device time;
 * ``idle_gaps``: the device's idle time split by what the host was doing,
-  the innermost benchmark span that covers each idle moment.
+  the innermost host span that covers each idle moment;
+* ``host_span_s``: total seconds of each host span;
+* ``clock_offset_ms``: bounds on how far the trace's device clock runs
+  behind its host clock (``clock_offset``), or None.  Where the engine's
+  spans give such bounds, the idle time is labelled with the host spans
+  moved onto the device clock by the middle of the bounds.
 
-``to_text_proto`` writes such a table back as an ``XSpace`` text proto, so
-that a few steps of a chip trace can be kept as a test fixture and read by
+``to_text_proto(cut(table, t0, t1))`` writes a few steps of such a table
+back as an ``XSpace`` text proto, each op's scope (``scopes.py``) riding on
+its event, so that a chip trace can be kept as a test fixture and read by
 the same code.
 """
 from __future__ import annotations
@@ -33,9 +40,14 @@ import tempfile
 import time
 
 # Host spans the driver and the engine put on the profiler's clock, from
-# the outermost in.
+# the outermost in: the engine's phases sit in the driver's ``bench.step``;
+# dispatch and wait in ``tsar_engine_step``.  The names are copied from the
+# program's ``repro.obs.trace``, as the peaks are in ``peaks.py``.
 HOST_SPANS = ("bench.step", "bench.submit", "bench.wait_arrival",
-              "bench.stamp", "tsar_engine_step")
+              "bench.stamp", "engine.admit", "engine.plan", "engine.sample",
+              "engine.emit", "tsar_engine_step", "engine.dispatch",
+              "engine.wait")
+SCOPE_STAT = "scope"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 TRACE_SECONDS = 4.0
@@ -116,10 +128,39 @@ def _label_idle(gaps: list, host: list) -> dict:
     return out
 
 
+def clock_offset(table: dict) -> tuple | None:
+    """Bounds (lower, upper), in ns, on how far the trace's device clock
+    runs behind its host clock, from the engine's spans: a step's program
+    (the longest execution beside ``engine.wait``) cannot end after the
+    host saw it end, and the next program, the sampler's, cannot start
+    before ``engine.sample`` opened.  None without those spans."""
+    dev = next(iter(table["devices"].values()), None)
+    waits = sorted((s, e) for n, s, e in table["host"] if n == "engine.wait")
+    samples = sorted(s for n, s, _ in table["host"] if n == "engine.sample")
+    if dev is None or not waits or not dev["modules"]:
+        return None
+    mods = sorted((s, e) for _, s, e in dev["modules"])
+    lo, hi = float("-inf"), float("inf")
+    for k, (ws, we) in enumerate(waits):
+        over = [(min(e, we) - max(s, ws), i) for i, (s, e) in enumerate(mods)
+                if s < we and e > ws]
+        if not over:
+            continue
+        i = max(over)[1]
+        hi = min(hi, we - mods[i][1])
+        nxt = waits[k + 1][0] if k + 1 < len(waits) else float("inf")
+        opened = [s for s in samples if we <= s < nxt]
+        if opened and i + 1 < len(mods):
+            lo = max(lo, opened[0] - mods[i + 1][0])
+    if hi == float("inf"):
+        return None
+    return lo, hi
+
+
 def reduce_table(table: dict) -> dict:
-    """Busy time, the programs' device time, the top device ops and the idle
-    time by host activity, all in seconds, over the trace's own span (first
-    to last event on any line)."""
+    """Busy time, the programs' device time, the top device ops, the idle
+    time by host activity and each host span's total, all in seconds, over
+    the trace's own span (first to last event on any line)."""
     per_chip_busy, ops_time, idle = [], {}, {}
     program_ns = 0.0
     every = [t for d in table["devices"].values()
@@ -128,60 +169,98 @@ def reduce_table(table: dict) -> dict:
     if not every:
         return {}
     lo, hi = min(every), max(every)
+    chips = max(len(table["devices"]), 1)
+    offset = clock_offset(table)
+    shift = sum(offset) / 2 if offset and offset[0] <= offset[1] else 0.0
+    host = [[n, s - shift, e - shift] for n, s, e in table["host"]]
     for dev in table["devices"].values():
         busy = _union([s, e] for _, s, e in dev["ops"])
         per_chip_busy.append(sum(e - s for s, e in busy))
         for name, s, e in dev["ops"]:
             ops_time[name] = ops_time.get(name, 0.0) + (e - s)
         program_ns += sum(e - s for _, s, e in dev["modules"])
-        for k, v in _label_idle(_gaps(busy, lo, hi), table["host"]).items():
-            idle[k] = idle.get(k, 0.0) + v / len(table["devices"])
-    chips = max(len(table["devices"]), 1)
-    top = lambda d: [[k, v * 1e-9] for k, v in  # noqa: E731
-                     sorted(d.items(), key=lambda kv: -kv[1])[:10]]
+        for k, v in _label_idle(_gaps(busy, lo, hi), host).items():
+            idle[k] = idle.get(k, 0.0) + v / chips
+    spans: dict = {}
+    for name, s, e in table["host"]:
+        spans[name] = spans.get(name, 0.0) + (e - s) * 1e-9
     return {"span_s": (hi - lo) * 1e-9,
             "busy_s": sum(per_chip_busy) / chips * 1e-9,
             "program_s": program_ns / chips * 1e-9,
-            "device_ops": top({k: v / chips for k, v in ops_time.items()}),
-            "idle_gaps": top(idle)}
+            "device_ops": top({k: v / chips for k, v in ops_time.items()},
+                              1e-9),
+            "idle_gaps": [[k, v * 1e-9] for k, v in
+                          sorted(idle.items(), key=lambda kv: -kv[1])],
+            "host_span_s": spans,
+            "clock_offset_ms": None if offset is None else [
+                offset[0] * 1e-6, offset[1] * 1e-6]}
+
+
+def top(d: dict, scale: float = 1.0) -> list:
+    """The ten largest entries of ``d`` as ``[[key, value * scale], ...]``."""
+    return [[k, v * scale] for k, v in
+            sorted(d.items(), key=lambda kv: -kv[1])[:10]]
 
 
 def to_text_proto(table: dict) -> str:
     """``table`` as an ``XSpace`` text proto (``ProfileData.from_text_proto``
-    reads it back)."""
+    reads it back); a device's ops carry their ``scopes``, where it has
+    them, as a ``SCOPE_STAT`` stat."""
     out = []
 
     def plane(pid: int, name: str, lines: dict) -> None:
         meta: dict = {}
         out.append(f"planes {{\n  id: {pid}\n  name: \"{name}\"")
         for lid, (lname, events) in enumerate(lines.items(), start=1):
-            base = min((s for _, s, _ in events), default=0)
+            base = min((ev[1] for ev in events), default=0)
             out.append(f"  lines {{\n    id: {lid}\n    name: \"{lname}\"\n"
                        f"    timestamp_ns: {int(base)}")
-            for n, s, e in events:
-                mid = meta.setdefault(n, len(meta) + 1)
+            for n, s, e, *scope in events:
+                mid = meta.setdefault(n, len(meta) + 2)
+                stat = (f" stats {{ metadata_id: 1 str_value: \"{scope[0]}\" }}"
+                        if scope and scope[0] else "")
                 out.append(f"    events {{ metadata_id: {mid} offset_ps: "
                            f"{int(round((s - base) * 1000))} duration_ps: "
-                           f"{int(round((e - s) * 1000))} }}")
+                           f"{int(round((e - s) * 1000))}{stat} }}")
             out.append("  }")
         for n, mid in meta.items():
             quoted = n.replace("\\", "\\\\").replace('"', '\\"')
             out.append(f"  event_metadata {{ key: {mid} value {{ id: {mid} "
                        f"name: \"{quoted}\" }} }}")
+        out.append(f"  stat_metadata {{ key: 1 value {{ id: 1 name: "
+                   f"\"{SCOPE_STAT}\" }} }}")
         out.append("}")
 
     for i, (name, dev) in enumerate(sorted(table["devices"].items())):
-        plane(i + 1, name, {OPS_LINE: dev["ops"], MODULES_LINE: dev["modules"]})
+        ops = dev["ops"]
+        if "scopes" in dev:
+            ops = [op + [sc] for op, sc in zip(ops, dev["scopes"])]
+        plane(i + 1, name, {OPS_LINE: ops, MODULES_LINE: dev["modules"]})
     plane(len(table["devices"]) + 1, "/host:CPU", {"python": table["host"]})
     return "\n".join(out) + "\n"
 
 
-class WindowTracer:
-    """Profiles the end of the window, between steps."""
+def cut(table: dict, t0: float, t1: float) -> dict:
+    """The events of ``table`` that start in [t0, t1)."""
+    keep = lambda evs: [ev for ev in evs if t0 <= ev[1] < t1]  # noqa: E731
+    devices = {}
+    for d, dev in table["devices"].items():
+        pick = [i for i, op in enumerate(dev["ops"]) if t0 <= op[1] < t1]
+        devices[d] = {"ops": [dev["ops"][i] for i in pick],
+                      "modules": keep(dev["modules"])}
+        if "scopes" in dev:
+            devices[d]["scopes"] = [dev["scopes"][i] for i in pick]
+    return {"devices": devices, "host": keep(table["host"])}
 
-    def __init__(self, chips: int = 1):
+
+class WindowTracer:
+    """Profiles the end of the window, between steps, and reduces the trace
+    with ``reduce_xspace(serialized XSpace, chips) -> dict``."""
+
+    def __init__(self, chips: int, reduce_xspace):
         self.dir = None
         self.chips = chips
+        self.reduce_xspace = reduce_xspace
         self.t0 = self.t1 = None
 
     def plan(self, w0: float, w1: float, arrivals=()) -> None:
@@ -215,18 +294,17 @@ class WindowTracer:
     def reduce(self) -> dict | None:
         """The reduction, with the traced window on the host clock (seconds
         from the window's opening); None if nothing was traced."""
-        import jax
-
         if self.dir is None:
             return None
         try:
             if self.t1 is None:
                 return None
             path = glob.glob(f"{self.dir}/**/*.xplane.pb", recursive=True)[0]
-            table = load(jax.profiler.ProfileData.from_file(path), self.chips)
+            with open(path, "rb") as f:
+                raw = f.read()
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
-        out = reduce_table(table)
+        out = self.reduce_xspace(raw, self.chips)
         out.update(t0=self.t0 - self.w0, t1=self.t1 - self.w0,
                    window_s=self.t1 - self.t0)
         return out
